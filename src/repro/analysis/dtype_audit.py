@@ -34,7 +34,7 @@ import dataclasses
 from typing import Dict, List, Sequence, Set, Tuple
 
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis import jaxpr_audit
 from repro.core import engine

@@ -31,7 +31,7 @@ import math
 from typing import Any, Callable, Dict, Iterable, List, Sequence, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.core import engine
 

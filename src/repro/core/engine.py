@@ -832,6 +832,42 @@ def _xla_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec) -> jax.Array:
     )
 
 
+# Datapath dtypes Mosaic refuses on TPU v5e (jax 0.9.0, libtpu 0.0.34),
+# found by compiling for a described v5e: an fp16 accumulator raises
+# "NotImplementedError: float16", fp16 tiles fail with "Invalid vector
+# type for load", and casts to fp16 fail to legalize
+# tpu.pack_subelements.  FP8 storage widened to bf16 or fp32 compiles.
+# Only v5e was tried; the refusal holds for every TPU kind.
+_CHIP_REFUSED_DTYPES = frozenset({"float16"})
+
+
+def _device_kind(operands) -> str:
+    """The kind of device the operands are placed on: a concrete array's
+    device, or the mesh a tracer's sharding names; else the default
+    device when it is a TPU."""
+    for a in operands:
+        if isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer):
+            return next(iter(a.devices())).device_kind
+        dev = getattr(jax.typeof(a).sharding.mesh, "abstract_device", None)
+        if dev is not None:
+            return dev.device_kind
+    dev = jax.devices()[0]
+    return dev.device_kind if dev.platform == "tpu" else "unknown TPU"
+
+
+def _require_chip_dtypes(what: str, dtypes, operands) -> None:
+    """Refuse a compiled Pallas dispatch whose datapath Mosaic cannot
+    lower, before Mosaic is reached; nothing falls back to another
+    backend."""
+    bad = sorted({jnp.dtype(d).name for d in dtypes} & _CHIP_REFUSED_DTYPES)
+    if bad:
+        kind = _device_kind(operands)
+        raise ValueError(
+            f"{what} cannot run on the 'pallas' backend on device kind "
+            f"{kind!r}: Mosaic does not compile a {'/'.join(bad)} datapath "
+            f"there; choose a bf16 or fp32 policy (tpu_bf16, fp32)")
+
+
 def _pallas_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec,
                interpret: bool = False, bias: Optional[jax.Array] = None,
                fuse_epilogue: bool = False,
@@ -852,6 +888,11 @@ def _pallas_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec,
     from repro.kernels import ops  # local import: kernels depend on core
 
     policy, tile, layout = spec.policy, spec.tile, spec.layout
+    if not interpret:
+        _require_chip_dtypes(
+            f"precision policy {policy.name!r}",
+            (policy.compute_dtype, policy.accum_dtype, policy.out_dtype,
+             xc.dtype, wc.dtype), (xc, wc))
     kw = dict(policy=policy, tile=tile, layout=layout, interpret=interpret,
               bias=bias if fuse_epilogue else None,
               epilogue=spec.epilogue if fuse_epilogue else None)
@@ -902,6 +943,9 @@ def _pallas_attention(kind: str, operands, *, interpret: bool = False,
     :class:`BackendSpec`): dispatch to the fused sweep kernels."""
     from repro.kernels import flash_attention, chunked_linear_attention
 
+    if not interpret:
+        _require_chip_dtypes(f"{kind} on {operands[0].dtype} operands",
+                             (o.dtype for o in operands), operands)
     if kind == "attention":
         q, k, v = operands
         return flash_attention.flash_attention_pallas(
@@ -921,7 +965,7 @@ register_backend(
     "xla", _xla_fn,
     capabilities=("layouts", "operand_dtypes"),
     description="lax.dot_general with the engine's precision policy "
-                "(production fallback; XLA:CPU dry-runs; epilogues applied "
+                "(the default off the TPU; XLA:CPU dry-runs; epilogues applied "
                 "post-op by the engine; transpose layouts fold into the "
                 "dot's dimension numbers; FP8 storage widens at the dot's "
                 "input — the cast fuses into the contraction)")

@@ -15,10 +15,3 @@ backends register alongside these without touching kernel code).
 * chunked_linear_attention.py -- VMEM-resident-state chunked recurrence
   (mLSTM / SSD), the store-once rule applied to linear attention.
 """
-
-from jax.experimental.pallas import tpu as _pltpu
-
-# renamed TPUCompilerParams -> CompilerParams across jax releases; every
-# kernel in this package uses this one alias
-CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-    or _pltpu.TPUCompilerParams

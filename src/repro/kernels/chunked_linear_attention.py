@@ -18,6 +18,14 @@ sequential):
 
 With log-decays g <= 0 every factor is exp(<=0): numerically stable with no
 extra stabilizer (same argument as models/ssm.py, which is the oracle).
+
+Mosaic has no cumsum, and a ``(1, chunk)`` block of a ``(BH, S)`` array
+breaks the (8, 128) tiling rule for chunks under 128 lanes.  So the wrapper
+takes the per-chunk prefix sums ``L`` in XLA (one pass over ``(BH, S)``
+fp32, small beside the q/k/v streams) and hands them to the kernel twice,
+as ``(BH, n, chunk, 1)`` columns for the row factors and as
+``(BH, n, 1, chunk)`` rows for ``L_i - L_j``: each block's last two dims
+are then whole array dims.
 """
 
 from __future__ import annotations
@@ -29,13 +37,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 __all__ = ["chunked_linear_attention_pallas"]
 
 
-def _kernel(q_ref, k_ref, v_ref, g_ref, o_ref, state_out_ref, state_ref,
-            *, n_chunks: int, chunk: int):
+def _kernel(q_ref, k_ref, v_ref, lc_ref, lr_ref, o_ref, state_out_ref,
+            state_ref, *, n_chunks: int, chunk: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -45,23 +51,23 @@ def _kernel(q_ref, k_ref, v_ref, g_ref, o_ref, state_out_ref, state_ref,
     q = q_ref[0].astype(jnp.float32)          # (c, dk)
     k = k_ref[0].astype(jnp.float32)          # (c, dk)
     v = v_ref[0].astype(jnp.float32)          # (c, dv)
-    g = g_ref[0].astype(jnp.float32)          # (c,)
-
-    L = jnp.cumsum(g)                          # (c,) inclusive
-    Ltot = L[-1]
+    L = lc_ref[0, 0]                           # (c, 1) inclusive prefix sums
+    L_row = lr_ref[0, 0]                       # (1, c) the same, as a row
 
     idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jdx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     causal = idx >= jdx
-    A = jnp.where(causal, jnp.exp(L[:, None] - L[None, :]), 0.0)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    Ltot = jnp.sum(jnp.where(last, L_row, 0.0))
+    A = jnp.where(causal, jnp.exp(L - L_row), 0.0)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * A
     out = jnp.dot(s, v, preferred_element_type=jnp.float32)
-    out = out + jnp.dot(q * jnp.exp(L)[:, None], state_ref[...],
+    out = out + jnp.dot(q * jnp.exp(L), state_ref[...],
                         preferred_element_type=jnp.float32)
 
-    kdec = k * jnp.exp(Ltot - L)[:, None]
+    kdec = k * jnp.exp(Ltot - L)
     state_ref[...] = (
         jnp.exp(Ltot) * state_ref[...]
         + jax.lax.dot_general(kdec, v, (((0,), (0,)), ((), ())),
@@ -93,6 +99,8 @@ def chunked_linear_attention_pallas(
     assert S % chunk == 0, (S, chunk)
     n_chunks = S // chunk
     grid = (BH, n_chunks)
+    L = jnp.cumsum(log_g.astype(jnp.float32).reshape(BH, n_chunks, chunk),
+                   axis=-1)
 
     out, state = pl.pallas_call(
         functools.partial(_kernel, n_chunks=n_chunks, chunk=chunk),
@@ -101,7 +109,8 @@ def chunked_linear_attention_pallas(
             pl.BlockSpec((1, chunk, dk), lambda h, j: (h, j, 0)),
             pl.BlockSpec((1, chunk, dk), lambda h, j: (h, j, 0)),
             pl.BlockSpec((1, chunk, dv), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, chunk), lambda h, j: (h, j)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda h, j: (h, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda h, j: (h, j, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, dv), lambda h, j: (h, j, 0)),
@@ -112,10 +121,10 @@ def chunked_linear_attention_pallas(
             jax.ShapeDtypeStruct((BH, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="redmule_chunked_linear_attention",
-    )(q, k, v, log_g)
+    )(q, k, v, L[..., None], L[:, :, None, :])
     return out, state

@@ -152,7 +152,7 @@ def redmule_matmul(
                                 interpret=interpret)
     if bias_grad:
         z, db = out
-        return z[:M, :K], db[0, :K]
+        return z[:M, :K], db[0, 0, :K]
     return out[:M, :K]
 
 

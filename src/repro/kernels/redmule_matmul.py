@@ -72,7 +72,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import epilogues as epi
 from repro.core import precision as prec
 from repro.core import tiling
-from repro.kernels import CompilerParams as _CompilerParams
 
 __all__ = ["redmule_matmul_pallas", "redmule_matmul_batched_pallas", "LAYOUTS"]
 
@@ -268,7 +267,7 @@ def _pipelined_kernel(*refs, n_steps: int, depth: int, tile, layout: str,
         acc_ref[...], None if bias_ref is None else bias_ref[...],
         epilogue=epilogue, out_dtype=out_dtype)
     if db_ref is not None:
-        db_ref[...] = db_acc[...]
+        db_ref[0] = db_acc[...]
 
 
 def _stored_tile_shapes(tile: tiling.TileConfig, layout: str):
@@ -316,9 +315,10 @@ def redmule_matmul_pallas(
     ``deriv`` must be stored exactly like the dZ operand — the x slot for
     "nt", the w slot for "tn").  ``bias_grad=True`` (only meaningful on the
     "tn" dW dispatch) returns ``(Z, db)`` where ``db`` is a
-    ``(M/bm, K)`` accum-dtype array whose every row is the full
+    ``(M/bm, 1, K)`` accum-dtype array whose every row is the full
     ``Σ_rows ds`` (each grid row sweeps the whole reduction; callers take
-    row 0).  ``pipeline_depth`` sets the number of buffer slots of the
+    row 0; the unit axis keeps the ``(1, bk)`` block a whole-dim block,
+    as Mosaic's (8, 128) tiling rule requires).  ``pipeline_depth`` sets the number of buffer slots of the
     in-kernel K-loop: 1 = single-buffered (each step's DMA issues and
     completes before its FMA — no overlap, the minimal-VMEM schedule),
     2 = classic double buffering, deeper = more DMAs in flight."""
@@ -348,22 +348,23 @@ def redmule_matmul_pallas(
     n_steps = N // tile.bn
     x_tile, w_tile = _stored_tile_shapes(tile, layout)
 
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY)]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
     operands = [x, w]
     if bias is not None:
         in_specs.append(pl.BlockSpec((1, tile.bk), lambda i, j: (0, j)))
         operands.append(bias)
     if grad_epilogue is not None:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         operands.append(deriv)
 
     out_shape = [jax.ShapeDtypeStruct((M, K), policy.out_dtype)]
     out_specs = [pl.BlockSpec((tile.bm, tile.bk), lambda i, j: (i, j))]
     if bias_grad:
         out_shape.append(
-            jax.ShapeDtypeStruct((grid[0], K), policy.accum_dtype))
-        out_specs.append(pl.BlockSpec((1, tile.bk), lambda i, j: (i, j)))
+            jax.ShapeDtypeStruct((grid[0], 1, K), policy.accum_dtype))
+        out_specs.append(
+            pl.BlockSpec((1, 1, tile.bk), lambda i, j: (i, 0, j)))
 
     scratch = [pltpu.VMEM((tile.bm, tile.bk), policy.accum_dtype),
                pltpu.VMEM((depth, *x_tile), x.dtype),
@@ -391,7 +392,7 @@ def redmule_matmul_pallas(
         out_specs=out_specs if bias_grad else out_specs[0],
         out_shape=out_shape if bias_grad else out_shape[0],
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -532,7 +533,7 @@ def redmule_matmul_batched_pallas(
                                lambda b, i, j, k: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M, K), policy.out_dtype),
         scratch_shapes=[pltpu.VMEM((tile.bm, tile.bk), policy.accum_dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

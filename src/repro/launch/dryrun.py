@@ -25,7 +25,7 @@ from repro.launch import train as train_lib
 from repro.models import transformer
 from repro.optim import AdamW
 from repro.roofline import analysis as roofline_lib
-from repro.runtime import compat, sharding
+from repro.runtime import sharding
 from repro.serving import specs as serving_specs
 
 __all__ = ["dryrun_cell", "main"]
@@ -101,7 +101,7 @@ def dryrun_cell(
 
     # every GEMM dispatched while the cell is traced lands in gemm_events;
     # the roofline consumes them instead of re-deriving shapes by hand
-    with compat.set_mesh(mesh), engine.instrument() as gemm_events:
+    with jax.set_mesh(mesh), engine.instrument() as gemm_events:
         if shape.kind == "train":
             rules = sharding.Rules(fsdp=fsdp, sequence_parallel=sequence_parallel)
             opt = AdamW(lr=1e-4)

@@ -29,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.core import engine
+from repro.launch import compile_cache
 from repro.models import transformer
 from repro.runtime import sharding
 from repro.serving import kv_cache as kv_lib
@@ -277,6 +278,7 @@ def main(argv=None):
                    help="--sched: KV checksum audit cadence in decode steps "
                         "(default: 1 when --inject is set, else off)")
     args = p.parse_args(argv)
+    compile_cache.enable()
 
     cfg = configs.get_reduced(args.arch) if args.reduced else configs.get(args.arch)
     rng = jax.random.PRNGKey(args.seed)

@@ -48,6 +48,7 @@ from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.core import engine
 from repro.data import Prefetcher, SyntheticLM
+from repro.launch import compile_cache
 from repro.models import transformer
 from repro.optim import (AdamW, Compressor, OptState, adjust,
                          clip_by_global_norm, init_scale, scale_loss,
@@ -181,12 +182,11 @@ def build_compressed_dp_train_step(
     genuinely per-host — each host accumulates the residual of *its* batch
     shard — so it carries an explicit leading host axis, sharded over the
     data axes.  Storing it "replicated" would silently checkpoint only
-    host 0's residual (shard_map's ``check_rep=False`` stamps the
+    host 0's residual (shard_map's ``check_vma=False`` stamps the
     out-spec without verifying it), breaking bit-identical kill/resume.
 
     Returns (step, init_fn) where state = (TrainState, ef_hosts).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Pspec
 
     dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -221,11 +221,11 @@ def build_compressed_dp_train_step(
         pspec = jax.tree.map(lambda _: Pspec(), state.params)
         espec = jax.tree.map(lambda _: Pspec(dp), ef_hosts)
         bspec = jax.tree.map(lambda _: Pspec(dp), batch)
-        mean_g, ef_hosts, loss = shard_map(
-            local, mesh,
+        mean_g, ef_hosts, loss = jax.shard_map(
+            local, mesh=mesh,
             in_specs=(pspec, espec, bspec),
             out_specs=(pspec, espec, Pspec()),
-            check_rep=False,
+            check_vma=False,
         )(state.params, ef_hosts, batch)
 
         mean_g, gnorm = clip_by_global_norm(mean_g, clip_norm)
@@ -302,9 +302,9 @@ def _print_goodput(out):
           f"restarts={g['restarts']}")
 
 
-def _compressed_dp_main(args, cfg):
+def _compressed_dp_main(args, cfg) -> float:
     """Data-parallel training with a compressed gradient wire (and the
-    fault-tolerant loop when --ckpt-dir is set)."""
+    fault-tolerant loop when --ckpt-dir is set); returns the final loss."""
     import json
 
     from repro.optim import Compressor
@@ -325,18 +325,10 @@ def _compressed_dp_main(args, cfg):
     comp = Compressor(args.compress)
     opt = AdamW(lr=args.lr, warmup_steps=10)
     step, init_fn = build_compressed_dp_train_step(cfg, opt, mesh, comp)
-    state = init_fn(jax.random.PRNGKey(args.seed))
     ds = SyntheticLM(
         vocab_size=cfg.vocab_size, seq_len=args.seq, global_batch=args.batch,
         seed=args.seed,
         embed_dim=cfg.d_model if cfg.input_mode == "embeddings" else 0)
-
-    if args.instrument:
-        wire = comp.wire_bytes(state[0].params)
-        full = Compressor("none").wire_bytes(state[0].params)
-        print(f"[ft] gradient wire: kind={comp.kind} "
-              f"bytes/step={wire} fp32_bytes/step={full} "
-              f"ratio={full / max(wire, 1):.2f}x")
 
     # Canonical placement — the bit-identical-resume invariant (mirrors
     # runtime/elastic.py).  A resumed process's first step receives host
@@ -350,8 +342,19 @@ def _compressed_dp_main(args, cfg):
     ts0, ef0 = jax.eval_shape(init_fn, jax.random.PRNGKey(args.seed))
     state_sh = (jax.tree.map(lambda _: rep, ts0),
                 jax.tree.map(lambda _: dp_sh, ef0))
+    # The state is built in its placement and each step overwrites it: at
+    # yi-9b widths two copies of the replicated state (and a state built
+    # whole on one device) do not fit a 16 GB chip.
+    state = jax.jit(init_fn, out_shardings=state_sh)(
+        jax.random.PRNGKey(args.seed))
     jstep = jax.jit(step, in_shardings=(state_sh, dp_sh),
-                    out_shardings=(state_sh, rep))
+                    out_shardings=(state_sh, rep), donate_argnums=(0,))
+    if args.instrument:
+        wire = comp.wire_bytes(state[0].params)
+        full = Compressor("none").wire_bytes(state[0].params)
+        print(f"[ft] gradient wire: kind={comp.kind} "
+              f"bytes/step={wire} fp32_bytes/step={full} "
+              f"ratio={full / max(wire, 1):.2f}x")
     injector = None
     if args.fail_step is not None:
         injector = FailureInjector(fail_at_step=args.fail_step,
@@ -386,6 +389,7 @@ def _compressed_dp_main(args, cfg):
         with open(args.result, "w") as f:
             json.dump(res, f, indent=1)
         print(f"[ft] result digests -> {args.result}")
+    return final_loss
 
 
 def _print_instrument_summary(events):
@@ -489,6 +493,7 @@ def main(argv=None):
                         "verification)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    compile_cache.enable()
 
     if args.arch == "ae":
         return _ae_main(args)

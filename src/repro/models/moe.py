@@ -28,7 +28,7 @@ from repro.core import engine
 from repro.core import precision as prec
 from repro.models import layers
 from repro.models.layers import Param
-from repro.runtime import compat, sharding
+from repro.runtime import sharding
 
 __all__ = ["moe_schema", "moe_forward"]
 
@@ -199,9 +199,9 @@ def moe_forward_shard_map(
     Requires: mesh with a "model" axis dividing n_routed; tokens already
     batch-sharded.  Falls back to ``moe_forward`` outside a mesh.
     """
-    mesh = compat.current_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     dp_size = 1
-    if mesh is not None and not mesh.empty:
+    if not mesh.empty:
         for a in ("pod", "data"):
             dp_size *= mesh.shape.get(a, 1)
     if (mesh is None or mesh.empty or "model" not in mesh.shape
@@ -209,7 +209,6 @@ def moe_forward_shard_map(
             or (x.shape[0] // max(dp_size, 1)) % mesh.shape["model"] != 0):
         return moe_forward(params, x, cfg, policy=policy)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mo = cfg.moe
@@ -303,9 +302,9 @@ def moe_forward_shard_map(
     # traced outside shard_map
     n_shards = dp_size * ep
     with engine.repeat(n_shards):
-        y, aux, z, drop = shard_map(
-            local_fn, mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+        y, aux, z, drop = jax.shard_map(
+            local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )(params["w_in"], params["w_out"], params["router"], x)
 
     if "shared" in params:

@@ -250,17 +250,16 @@ def compressed_mean_allreduce(grads, ef, compressor: Compressor, mesh,
     grads must be replicated across the DP axes *within* each shard's view
     (i.e. per-shard local gradients); returns (mean_grads fp32, new_ef).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if compressor.kind == "none":
         def mean_fn(g):
             return jax.tree.map(
                 lambda x: jax.lax.pmean(x.astype(jnp.float32), axis_names), g)
-        f = shard_map(mean_fn, mesh,
-                      in_specs=(jax.tree.map(lambda _: P(), grads),),
-                      out_specs=jax.tree.map(lambda _: P(), grads),
-                      check_rep=False)
+        f = jax.shard_map(mean_fn, mesh=mesh,
+                          in_specs=(jax.tree.map(lambda _: P(), grads),),
+                          out_specs=jax.tree.map(lambda _: P(), grads),
+                          check_vma=False)
         return f(grads), ef
 
     def local_fn(g, e):
@@ -270,6 +269,6 @@ def compressed_mean_allreduce(grads, ef, compressor: Compressor, mesh,
 
     specs_g = jax.tree.map(lambda _: P(), grads)
     specs_e = jax.tree.map(lambda _: P(), ef)
-    f = shard_map(local_fn, mesh, in_specs=(specs_g, specs_e),
-                  out_specs=(specs_g, specs_e), check_rep=False)
+    f = jax.shard_map(local_fn, mesh=mesh, in_specs=(specs_g, specs_e),
+                      out_specs=(specs_g, specs_e), check_vma=False)
     return f(grads, ef)

@@ -1,62 +1,25 @@
-"""Version-compat shims for the jax mesh/sharding API.
-
-The framework targets the current jax mesh surface (``jax.set_mesh``,
-``jax.sharding.get_abstract_mesh``, ``AxisType``-typed meshes); older
-releases (<= 0.4.x) expose the same capabilities under different names and
-signatures.  Everything mesh-related routes through this module so the
-difference lives in exactly one place.
-"""
+"""Every mesh the framework builds, with ``Auto`` axis types, so GSPMD
+keeps propagating shardings the way the model code expects."""
 
 from __future__ import annotations
 
-import contextlib
 from typing import Sequence
 
 import jax
 
-__all__ = ["make_mesh", "abstract_mesh", "set_mesh", "current_abstract_mesh"]
+__all__ = ["make_mesh", "abstract_mesh"]
 
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
+
+def _auto(axes: Sequence[str]):
+    return (jax.sharding.AxisType.Auto,) * len(tuple(axes))
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axes)))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=_auto(axes))
 
 
 def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
     """Shape-only mesh stand-in (device-free spec sanitization in tests)."""
-    if _HAS_AXIS_TYPE:
-        return jax.sharding.AbstractMesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axes)))
-    return jax.sharding.AbstractMesh(tuple(zip(tuple(axes), tuple(shape))))
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """``jax.set_mesh`` context; falls back to the Mesh context manager."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
-
-
-def current_abstract_mesh():
-    """The active mesh, or None/empty outside any mesh context.
-
-    New jax returns the abstract mesh from the sharding context; the old-API
-    fallback returns the *physical* mesh entered via :func:`set_mesh` — it
-    exposes the same ``.empty`` / ``.shape`` surface and, unlike its
-    ``.abstract_mesh`` view, is accepted by ``shard_map`` on old jax."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import thread_resources
-
-    return thread_resources.env.physical_mesh
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes),
+                                     axis_types=_auto(axes))

@@ -57,7 +57,6 @@ def _build(args):
     """Construct (step_fn, init_state, batch_fn) — lazy jax imports."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.optim import AdamW, Compressor
@@ -89,7 +88,7 @@ def _build(args):
         # per-host — each host accumulates the residual of *its* batch
         # shard — so it carries an explicit leading host axis, sharded
         # P("data").  Storing it "replicated" would silently checkpoint
-        # only host 0's residual (shard_map's check_rep=False stamps the
+        # only host 0's residual (shard_map's check_vma=False stamps the
         # out-spec without verifying it), breaking bit-identical resume.
         ef = comp.init(params)
         if ef is not None:
@@ -128,11 +127,11 @@ def _build(args):
     espec = jax.tree.map(lambda _: P("data"), state0["ef"])
     bspec = {"x": P("data"), "y": P("data")}
 
-    sharded_local = shard_map(
-        local, mesh,
+    sharded_local = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(pspec, espec, bspec),
         out_specs=(pspec, espec, P()),
-        check_rep=False)
+        check_vma=False)
 
     def step_fn(state, batch):
         mean_g, ef2, loss = sharded_local(state["params"], state["ef"], batch)

@@ -29,8 +29,6 @@ from typing import Dict, Optional, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime import compat
-
 __all__ = [
     "Rules",
     "use_rules",
@@ -201,8 +199,8 @@ def constrain(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     rules = current_rules()
     if rules is None:
         return x
-    mesh = compat.current_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     spec = sanitize_spec(logical_spec(axes, rules), x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, spec)

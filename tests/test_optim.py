@@ -211,7 +211,6 @@ def test_per_host_scales_match_fp32_oracle():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.optim import Compressor
 from repro.runtime import compat
@@ -236,8 +235,8 @@ for kind in ("int8", "fp8_e4m3", "fp8_e5m2"):
         return sent / n_steps
 
     espec = jax.tree.map(lambda _: P(), ef0)
-    f = shard_map(local, mesh, in_specs=(P("data"), espec),
-                  out_specs=P(), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(P("data"), espec),
+                      out_specs=P(), check_vma=False)
     out = np.asarray(jax.jit(f)(jnp.asarray(g), ef0))
     rel = float(np.max(np.abs(out - oracle)) / np.max(np.abs(oracle)))
     print(kind, "rel_err_vs_oracle:", rel)
@@ -275,7 +274,7 @@ toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)
 batch = {"inputs": toks, "labels": toks}
 
 results = {}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for kind in ("none", "fp16"):
         comp = Compressor(kind)
         step, init_fn = build_compressed_dp_train_step(cfg, opt, mesh, comp)

@@ -70,7 +70,7 @@ def test_constrain_inside_jit_applies():
         with sharding.use_rules(rules):
             return sharding.constrain(x * 1.0, "batch", "ff")
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         txt = jax.jit(f).lower(jax.ShapeDtypeStruct((4, 4), jnp.float32)).as_text()
     assert "sharding" in txt.lower()
 
@@ -86,7 +86,7 @@ def test_constrain_fb_grad_path():
             y = sharding.constrain_fb(v * 2.0, ("batch",), (None,))
             return jnp.sum(y ** 2)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g = jax.jit(jax.grad(f))(x)
     np.testing.assert_allclose(np.asarray(g), np.asarray(8.0 * x))
 
@@ -166,7 +166,7 @@ from repro.runtime import compat
 mesh = compat.make_mesh((1, 2), ("data", "model"))
 def f(x, w):
     return jnp.sum((x @ w).astype(jnp.float32))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     c = jax.jit(f,
         in_shardings=(NamedSharding(mesh, P(None, None)),
                       NamedSharding(mesh, P(None, "model"))),
@@ -194,7 +194,7 @@ def test_structural_costs_count_dot_flops():
     def f(x, w):
         return x @ w
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(f).lower(
             jax.ShapeDtypeStruct((M, N), jnp.float32),
             jax.ShapeDtypeStruct((N, K), jnp.float32)).compile()
@@ -215,7 +215,7 @@ def test_structural_costs_scan_trip_multiplier():
         h, _ = jax.lax.scan(body, x, ws)
         return h
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(f).lower(
             jax.ShapeDtypeStruct((L, D, D), jnp.float32),
             jax.ShapeDtypeStruct((D, D), jnp.float32)).compile()

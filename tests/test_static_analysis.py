@@ -146,7 +146,7 @@ def test_ratchet_stale_entry_fails():
 # dtype auditor
 # --------------------------------------------------------------------- #
 def test_dtype_audit_flags_planted_fp64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def model(x):
             return jnp.sum(x.astype(jnp.float64) * 2.0)
 
