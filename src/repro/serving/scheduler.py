@@ -17,6 +17,13 @@ Time is a virtual clock: one tick per batched decode step,
 FIFO by ``(arrival, rid)``, lowest free slot wins, greedy argmax decode —
 so a seeded arrival trace pins the full admit/prefill/finish event log.
 
+Spans (docs/serving.md): ``step`` and the work inside it run under
+``jax.profiler.TraceAnnotation`` spans named ``serve.*`` (the ``SPAN_*``
+constants), which land in the profiler's host plane on the device trace's
+clock when a profiler runs, and cost about a microsecond each when none
+does.  Each request's ``RequestResult`` carries its host-clock queue wait
+(``queue_s``), which its ``serve.prefill`` span repeats as ``queued_ms``.
+
 Resilience layer (docs/serving.md failure model): requests may carry a
 ``deadline_ticks`` budget — expired work is evicted whether queued or
 mid-decode (the drain invariant makes mid-flight eviction safe: the
@@ -43,6 +50,7 @@ is billed as waste slot-ticks in the
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -59,6 +67,17 @@ __all__ = [
     "Request", "SchedulerConfig", "RequestResult", "Scheduler",
     "instrumented_decode_events",
 ]
+
+_span = jax.profiler.TraceAnnotation
+# Span names; the benchmark's trace readers match them, so a rename
+# silences them.
+SPAN_STEP = "serve.step"             # all of Scheduler.step()
+SPAN_ADMIT = "serve.admit"           # evict/admit/shed; queued = queue after
+SPAN_PREFILL = "serve.prefill"       # one request: prefill, insert, 1st token
+SPAN_DECODE = "serve.decode"         # one batched decode step
+SPAN_FETCH = "serve.decode.fetch"    # waits for decode, logits to the host
+SPAN_SAMPLE = "serve.decode.sample"  # NaN guard, argmax, slot bookkeeping
+SPAN_RECOVER = "serve.recover"       # one slot's cache rebuild
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,6 +116,8 @@ class RequestResult:
     tokens: List[int] = dataclasses.field(default_factory=list)
     final_logits: Optional[np.ndarray] = None  # P(next token | full sequence)
     status: str = "pending"  # pending|finished|rejected|expired|shed
+    submitted_s: Optional[float] = None  # host perf_counter() at submit
+    queue_s: Optional[float] = None      # host s, submit to prefill start
 
     @property
     def ttft(self) -> float:
@@ -216,7 +237,8 @@ class Scheduler:
             if len(r.prompt) + r.max_new_tokens > self.scfg.max_len:
                 self._reject(r, "oversized", None)
                 continue
-            self.results[r.rid] = RequestResult(rid=r.rid, arrival=r.arrival)
+            self.results[r.rid] = RequestResult(
+                rid=r.rid, arrival=r.arrival, submitted_s=time.perf_counter())
             accepted.append(r)
         self.pending.extend(accepted)
         self.pending.sort(key=lambda r: (r.arrival, r.rid))
@@ -245,6 +267,13 @@ class Scheduler:
                     continue
             self.queue.append(r)
             self.trace.append(("admit", self.clock, r.rid))
+
+    def _admission(self, *work) -> None:
+        """Run admission work under one ``serve.admit`` span."""
+        with _span(SPAN_ADMIT) as span:
+            for fn in work:
+                fn()
+            span.set_metadata(queued=len(self.queue))
 
     def _shed(self) -> None:
         # runs after _start: only work still *waiting* once the free slots
@@ -340,23 +369,28 @@ class Scheduler:
                 # expired while a co-resident prefill moved the clock
                 self._expire(r, "queued")
                 continue
-            prompt_np = np.asarray(r.prompt, np.int32)
-            prompt = jnp.asarray(prompt_np)[None]
-            logits, single = self._guarded_prefill(prompt, r.rid)
-            self.cache = self._insert(self.cache, single, jnp.int32(slot))
-            tok = int(jnp.argmax(logits[0]))
-            self.clock += self.scfg.prefill_ticks
             res = self.results[r.rid]
-            res.first_token_tick = self.clock
-            res.tokens.append(tok)
-            self.slots[slot] = _Slot(
-                rid=r.rid, pos=prompt.shape[1], emitted=1, fed=0,
-                max_new=r.max_new_tokens, last_token=tok,
-                prompt=prompt_np, deadline=r.deadline, priority=r.priority)
-            self._arm_guards()
-            self.trace.append(
-                ("prefill", self.clock, r.rid, slot, prompt.shape[1]))
-            self._admit()  # the clock moved; later arrivals may be due now
+            res.queue_s = time.perf_counter() - res.submitted_s
+            prompt_np = np.asarray(r.prompt, np.int32)
+            with _span(SPAN_PREFILL, rid=r.rid, plen=len(prompt_np),
+                       queued_ms=1e3 * res.queue_s):
+                prompt = jnp.asarray(prompt_np)[None]
+                logits, single = self._guarded_prefill(prompt, r.rid)
+                self.cache = self._insert(self.cache, single, jnp.int32(slot))
+                tok = int(jnp.argmax(logits[0]))
+                self.clock += self.scfg.prefill_ticks
+                res.first_token_tick = self.clock
+                res.tokens.append(tok)
+                self.slots[slot] = _Slot(
+                    rid=r.rid, pos=prompt.shape[1], emitted=1, fed=0,
+                    max_new=r.max_new_tokens, last_token=tok,
+                    prompt=prompt_np, deadline=r.deadline,
+                    priority=r.priority)
+                self._arm_guards()
+                self.trace.append(
+                    ("prefill", self.clock, r.rid, slot, prompt.shape[1]))
+            # the clock moved; later arrivals may be due now
+            self._admission(self._admit)
 
     # ----------------------------------------------------------------- #
     # Integrity: checksum guards, quarantine, slot rebuild
@@ -414,24 +448,26 @@ class Scheduler:
         cache alone restores the invariant.  The virtual clock does not
         advance — recovery overlaps the pool and is billed as waste
         slot-ticks by the caller."""
-        res = self.results[s.rid]
-        absorbed = np.concatenate(
-            [np.asarray(s.prompt, np.int32),
-             np.asarray(res.tokens[:s.fed], np.int32)])
-        assert absorbed.shape[0] == s.pos, "slot rows out of sync"
-        seq = jnp.asarray(absorbed)[None]
-        _, single = self._prefill_fn(seq.shape[1], recover=True)(
-            self.params, seq)
-        row = None
-        if rerun_decode:
-            logits1, single = self._recover_decode(
-                self.params, single,
-                jnp.asarray([[s.last_token]], np.int32),
-                jnp.asarray([s.pos], np.int32),
-                jnp.asarray([s.pos + 1], np.int32))
-            row = np.asarray(logits1[0])
-        self.cache = self._recover_insert(self.cache, single, jnp.int32(slot))
-        return row
+        with _span(SPAN_RECOVER, rid=s.rid):
+            res = self.results[s.rid]
+            absorbed = np.concatenate(
+                [np.asarray(s.prompt, np.int32),
+                 np.asarray(res.tokens[:s.fed], np.int32)])
+            assert absorbed.shape[0] == s.pos, "slot rows out of sync"
+            seq = jnp.asarray(absorbed)[None]
+            _, single = self._prefill_fn(seq.shape[1], recover=True)(
+                self.params, seq)
+            row = None
+            if rerun_decode:
+                logits1, single = self._recover_decode(
+                    self.params, single,
+                    jnp.asarray([[s.last_token]], np.int32),
+                    jnp.asarray([s.pos], np.int32),
+                    jnp.asarray([s.pos + 1], np.int32))
+                row = np.asarray(logits1[0])
+            self.cache = self._recover_insert(
+                self.cache, single, jnp.int32(slot))
+            return row
 
     def _victim_slot(self) -> Optional[int]:
         active = self._active()
@@ -472,7 +508,15 @@ class Scheduler:
         self.clock += 1.0
         self.decode_steps += 1
         self.goodput.on_decode_step()
-        logits = np.array(logits)  # host copy: rows may be replaced below
+        with _span(SPAN_FETCH):
+            logits = np.array(logits)  # host copy: rows may be replaced below
+        with _span(SPAN_SAMPLE):
+            self._sample(logits)
+
+    def _sample(self, logits: np.ndarray) -> None:
+        """The host's half of a decode step: NaN guard, greedy tokens, slot
+        bookkeeping, checksum guards and the health record."""
+        n = self.scfg.n_slots
         if (self.injector is not None
                 and self.injector.mode == "nan_logits"
                 and self._active()
@@ -528,10 +572,6 @@ class Scheduler:
             "pending": len(self.pending),
             "active_slots": active,
             "batch_fill": active / n,
-            "goodput": self.goodput.goodput,
-            "recoveries": self.goodput.recoveries,
-            "expired": self.goodput.expired,
-            "rejected": self.goodput.rejected,
         })
 
     # ----------------------------------------------------------------- #
@@ -539,17 +579,20 @@ class Scheduler:
     # ----------------------------------------------------------------- #
     def step(self) -> bool:
         """Advance one scheduler event; False once fully drained."""
-        self._evict_expired()
-        self._admit()
-        self._start()
-        self._shed()
-        if self._active():
-            self._decode_once()
-            return True
-        if self.pending:  # idle until the next arrival
-            self.clock = max(self.clock, self.pending[0].arrival)
-            return True
-        return False
+        with _span(SPAN_STEP):
+            self._admission(self._evict_expired, self._admit)
+            self._start()
+            self._admission(self._shed)
+            active = len(self._active())
+            if active:
+                with _span(SPAN_DECODE, active=active,
+                           slots=self.scfg.n_slots):
+                    self._decode_once()
+                return True
+            if self.pending:  # idle until the next arrival
+                self.clock = max(self.clock, self.pending[0].arrival)
+                return True
+            return False
 
     def run(self) -> List[RequestResult]:
         while self.step():
