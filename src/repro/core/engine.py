@@ -211,7 +211,9 @@ class GemmSpec:
         flops/bytes accounting so masked rows are not billed.  None means
         dense (or the sizes were traced and unknowable at trace time).
       ragged_dim: which logical dim ``valid_rows`` masks — "m" (forward and
-        dX: ragged output rows) or "n" (dW: ragged contraction rows).
+        dX: ragged output rows), "n" (dW: ragged contraction rows) or "k"
+        (an "nt" grouped forward: ragged output columns, i.e. the stored
+        rows of the w operand).
       grad_epilogue: on a backward dispatch, the activation whose derivative
         feeds this GEMM (``ds = dZ * act'``); None on forward dispatches
         and epilogue-free backwards.
@@ -276,9 +278,9 @@ class GemmSpec:
         if self.layout not in ("nn", "nt", "tn"):
             raise ValueError(
                 f"GemmSpec.layout = {self.layout!r}; known: ('nn', 'nt', 'tn')")
-        if self.ragged_dim not in ("m", "n"):
-            raise ValueError(
-                f"GemmSpec.ragged_dim = {self.ragged_dim!r}; known: ('m', 'n')")
+        if self.ragged_dim not in ("m", "n", "k"):
+            raise ValueError(f"GemmSpec.ragged_dim = {self.ragged_dim!r}; "
+                             f"known: ('m', 'n', 'k')")
         # a typo'd dtype fails here, naming the field, instead of deep in
         # Pallas lowering (one validator shared with Policy)
         for f in ("x_dtype", "w_dtype"):
@@ -296,6 +298,8 @@ class GemmSpec:
             return 2 * self.batch * self.groups * self.m * self.n * self.k
         if self.ragged_dim == "m":
             return 2 * self.batch * self.valid_rows * self.n * self.k
+        if self.ragged_dim == "k":
+            return 2 * self.batch * self.m * self.n * self.valid_rows
         return 2 * self.batch * self.m * self.valid_rows * self.k
 
     @property
@@ -323,8 +327,8 @@ class GemmSpec:
         When ``w_shared`` the weight operand is read once per group, not
         once per batch element (weight GEMMs: one (N, K) matrix serves the
         whole batch).  Ragged grouped GEMMs (``valid_rows``) bill only the
-        valid rows of the ragged operand(s) and — for ``ragged_dim == "m"``
-        — of the output.
+        valid rows of the ragged operand(s) and — for ``ragged_dim`` "m"
+        and "k" — of the output.
 
         Backward-epilogue traffic is billed where it actually flows:
         ``*_dact`` pass events (the two-pass fallback) pay the full
@@ -368,6 +372,11 @@ class GemmSpec:
             x_elems = self.batch * self.valid_rows * self.n
             z_elems = self.batch * self.valid_rows * self.k
             w_elems = (self.groups if self.w_shared else bg) * self.n * self.k
+        elif self.ragged_dim == "k":  # ragged output columns (w's rows)
+            x_elems = bg * self.m * self.n
+            z_elems = self.batch * self.m * self.valid_rows
+            w_elems = (1 if self.w_shared else self.batch) \
+                * self.valid_rows * self.n
         else:  # ragged contraction rows (the dW dispatch)
             x_elems = self.batch * self.m * self.valid_rows
             z_elems = bg * self.m * self.k
@@ -1434,7 +1443,17 @@ def _quantized_bwd(ctx: _GradCtx, xd: jax.Array, wd: jax.Array,
 
 def _gemm_bwd(ctx: _GradCtx, res, dz: jax.Array):
     xd, wd, sx, sw = res
+    nt = ctx.spec.layout == "nt"
+    if ctx.spec.ragged_dim == "k":
+        # a grouped "nt" forward (Z = X·Wᵀ, ragged output columns): the
+        # "nn" rules on the stored w's transpose, billed dense
+        ctx = dataclasses.replace(ctx, spec=dataclasses.replace(
+            ctx.spec, layout="nn", valid_rows=None, ragged_dim="m"))
+    if nt:
+        wd = jnp.swapaxes(wd, -1, -2)
     dx, dw = _quantized_bwd(ctx, xd, wd, sx, sw, dz)
+    if nt:
+        dw = jnp.swapaxes(dw, -1, -2)
     return dx.astype(ctx.x_dtype), dw.astype(ctx.w_dtype)
 
 
@@ -2145,6 +2164,7 @@ class Engine:
         w: jax.Array,
         *,
         group_sizes: Optional[jax.Array] = None,
+        layout: str = "nn",
         policy=None,
         tile: Optional[tiling.TileConfig] = None,
         backend: Optional[str] = None,
@@ -2164,39 +2184,60 @@ class Engine:
         with the *valid* work, not ``G * M`` — forward and backward alike.
         Traced (data-dependent) sizes fall back to the dense count.
 
+        ``layout="nt"`` takes ``w`` stored ``(G, K, N)`` — ``Z[g] = X[g] @
+        W[g]ᵀ`` with no materialized transpose on backends with the
+        "layouts" capability (decode attention's scores against the key
+        cache).  There ``group_sizes`` count the valid stored rows of
+        ``w``, i.e. the valid output *columns*: columns at or beyond a
+        group's size are zeroed and billed as ``ragged_dim="k"``.
+
         Backward: dX/dW run as batched transpose-layout dispatches per
         group (``matmul_dx`` / ``matmul_dw`` events); the masked rows'
         cotangent is zeroed by the ``where``'s own autodiff, so invalid
-        rows contribute nothing to dW."""
+        rows contribute nothing to dW.  An "nt" forward differentiates
+        through the same dispatches on ``w``'s transpose, billed dense."""
         policy = self.resolve_policy(policy)
         b = self.resolve_backend(backend)
+        if layout not in ("nn", "nt"):
+            raise ValueError(
+                f"grouped_matmul layout = {layout!r}; known: ('nn', 'nt')")
+        nt = layout == "nt"
         if x.ndim < 3 or w.ndim != 3:
             raise ValueError(
-                f"grouped_matmul needs x (..., G, M, N) and w (G, N, K); "
-                f"got {x.shape} @ {w.shape}")
+                f"grouped_matmul needs x (..., G, M, N) and w (G, N, K) "
+                f"(or (G, K, N) under 'nt'); got {x.shape} @ {w.shape}")
         if x.shape[-3] != w.shape[0]:
             raise ValueError(
                 f"group mismatch: x has {x.shape[-3]} groups, w has {w.shape[0]}")
-        if x.shape[-1] != w.shape[-2]:
-            raise ValueError(f"contraction mismatch: {x.shape} @ {w.shape}")
+        if x.shape[-1] != w.shape[-1 if nt else -2]:
+            raise ValueError(f"contraction mismatch: {x.shape} @ {w.shape}"
+                             f" ({layout})")
         lead = x.shape[:-3]
-        m, n, k = x.shape[-2], x.shape[-1], w.shape[-1]
+        m, n = x.shape[-2], x.shape[-1]
+        k = w.shape[-2] if nt else w.shape[-1]
+        if nt and not get_backend(b).supports("layouts"):
+            w, layout = jnp.swapaxes(w, -1, -2), "nn"
         xs, ws, _ = _dispatch_storage(policy, b)
         tile = self.resolve_tile(tile, m=m, n=n, k=k, policy=policy,
-                                 backend=b, x_dtype=xs, w_dtype=ws)
+                                 backend=b, layout=layout,
+                                 x_dtype=xs, w_dtype=ws)
         spec = GemmSpec(
             op="grouped_matmul", tag="gmn,gnk->gmk", m=m, n=n, k=k,
             batch=int(np.prod(lead, dtype=np.int64)) if lead else 1,
             groups=w.shape[0],
-            policy=policy, tile=tile, w_shared=True,
-            valid_rows=_static_valid_rows(group_sizes, m), ragged_dim="m",
+            policy=policy, tile=tile, w_shared=True, layout=layout,
+            valid_rows=_static_valid_rows(group_sizes, k if nt else m),
+            ragged_dim="k" if nt else "m",
             x_dtype=xs, w_dtype=ws, scaled=policy.scaled,
         )
         z = _gemm_call(_make_ctx(spec, b, x, w), x, w)
         if group_sizes is not None:
-            valid = (jnp.arange(spec.m)[None, :]
-                     < jnp.asarray(group_sizes)[:, None])      # (G, M)
-            z = jnp.where(valid[..., None], z, jnp.zeros((), z.dtype))
+            size = jnp.asarray(group_sizes)[:, None]
+            if nt:
+                valid = (jnp.arange(k)[None, :] < size)[:, None, :]
+            else:
+                valid = (jnp.arange(m)[None, :] < size)[..., None]
+            z = jnp.where(valid, z, jnp.zeros((), z.dtype))
         return z
 
     def einsum2d(

@@ -311,32 +311,40 @@ def _ragged_decode_attention(
 ) -> jax.Array:
     """Mixed-length decode batch through the ragged grouped-GEMM path.
 
-    The score contraction runs transposed — ``scores^T[g] = K[g] @ q[g]^T``
-    with one group per (slot, kv-head) and ``group_sizes`` = the slot's
-    valid kv length — so the Engine's ``valid_rows`` accounting bills only
-    the rows each slot actually attends, not ``B * T`` dense.  Rows at or
-    beyond a group's size come back zeroed and are re-masked to -inf by
-    the softmax mask, so numerics match the dense block exactly.  The PV
-    contraction keeps the dense batched dispatch: its ragged dim is the
-    *contraction* (masked probabilities are exact zeros), which forward
-    grouped GEMMs cannot bill raggedly."""
+    The GQA query group sits in the rows of both cache contractions: each
+    (slot, kv-head) is one batch entry with M = G query rows, and K and V
+    are read once, in their stored ``(B·Hkv, T, hd)`` layout — nothing is
+    broadcast over G, and T is the scores' lane dimension.
+
+    Scores ``(B·Hkv, G, hd) · Kᵀ -> (B·Hkv, G, T)`` dispatch through the
+    Engine's ragged ``grouped_matmul`` under the "nt" layout (K is never
+    transposed), one group per (slot, kv-head) with ``group_sizes`` = the
+    slot's valid kv length: the Engine's ``valid_rows`` accounting bills
+    ``sum(sizes) · Hkv`` kv rows on the ragged T dimension, not ``B · T``
+    dense.  Columns at or beyond a group's size come back zeroed and are
+    re-masked to -inf by the softmax mask, so numerics match the dense
+    block exactly.  PV ``(B·Hkv, G, T) · (B·Hkv, T, hdv)`` is a dense
+    batched dispatch: its ragged dim is the *contraction* (masked
+    probabilities are exact zeros), which forward grouped GEMMs cannot
+    bill raggedly."""
     B, Hkv, G, S, hd = q.shape
     T = k.shape[2]
-    x = k.reshape(B * Hkv, T, hd)
-    w = jnp.transpose(q[:, :, :, 0, :], (0, 1, 3, 2)).reshape(B * Hkv, hd, G)
     sizes = kv_group_sizes
     if isinstance(sizes, (list, tuple)):
         sizes = np.asarray(sizes, np.int32)
     gs = (np.repeat(sizes, Hkv) if isinstance(sizes, np.ndarray)
           else jnp.repeat(jnp.asarray(sizes), Hkv))
-    st = engine.grouped_matmul(x, w, group_sizes=gs, policy=scores_policy)
-    s = jnp.transpose(st.reshape(B, Hkv, T, G), (0, 1, 3, 2))[:, :, :, None, :]
-    s = s * scale
+    s = engine.grouped_matmul(
+        q.reshape(B * Hkv, G, hd), k.reshape(B * Hkv, T, hd),
+        group_sizes=gs, layout="nt", policy=scores_policy)
+    s = s.reshape(B, Hkv, G, 1, T) * scale
     off = jnp.asarray(q_offset)
     rows = off[:, None] if off.ndim == 1 else off + jnp.arange(1)
     p = _masked_softmax_block(s, rows, kv_valid, True, window)
-    return engine.matmul(
-        p.astype(policy.compute_dtype), v[:, :, None], policy=policy)
+    out = engine.matmul(
+        p.reshape(B, Hkv, G, T).astype(policy.compute_dtype), v,
+        policy=policy)
+    return out[:, :, :, None, :]
 
 
 # --------------------------------------------------------------------- #

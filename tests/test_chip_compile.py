@@ -78,6 +78,25 @@ def test_redmule_matmul_decode_shape_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_decode_attention_dispatches_compile(one_chip):
+    # ragged decode attention over 32 slots of 4096: the query group in the
+    # rows, scores on the batched "nt" entry, PV on the batched "nn" one
+    B, T, G = 32, 4096, HQ // HKV
+
+    def attend(q, k, v, sizes):
+        s = engine.grouped_matmul(q, k, group_sizes=sizes, layout="nt",
+                                  policy=prec.TPU_BF16, backend="pallas")
+        return engine.matmul(s.astype(jnp.bfloat16), v,
+                             policy=prec.TPU_BF16, backend="pallas")
+
+    text = _compile(attend, _sds(one_chip, (B * HKV, G, HD)),
+                    _sds(one_chip, (B * HKV, T, HD)),
+                    _sds(one_chip, (B * HKV, T, HD)),
+                    _sds(one_chip, (B * HKV,), jnp.int32))
+    assert text.count("tpu_custom_call") >= 2
+    assert "redmule_matmul_batched_nt" in text
+
+
 def test_flash_attention_gqa_causal_compiles(one_chip):
     S = 1024
     text = _compile(

@@ -291,7 +291,7 @@ def test_gemmspec_validates_dtypes_and_enums_at_construction():
     with pytest.raises(ValueError, match="GemmSpec.layout"):
         engine.GemmSpec(op="matmul", tag="t", m=8, n=8, k=8, layout="tt")
     with pytest.raises(ValueError, match="GemmSpec.ragged_dim"):
-        engine.GemmSpec(op="matmul", tag="t", m=8, n=8, k=8, ragged_dim="k")
+        engine.GemmSpec(op="matmul", tag="t", m=8, n=8, k=8, ragged_dim="b")
 
 
 def test_resolve_rejects_unknown_policy_naming_registry():
