@@ -9,6 +9,7 @@ from repro.configs import (
     command_r_35b,
     deepseek_moe_16b,
     deepseek_v2_lite_16b,
+    granite_4_0_h_micro,
     hymba_1_5b,
     mistral_nemo_12b,
     musicgen_medium,
@@ -30,6 +31,7 @@ _MODULES = (
     xlstm_1_3b,
     hymba_1_5b,
     pixtral_12b,
+    granite_4_0_h_micro,
 )
 
 REGISTRY: Dict[str, Tuple[Callable[[], ModelConfig], Callable[[], ModelConfig]]] = {

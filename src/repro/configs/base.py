@@ -53,6 +53,13 @@ class SSMConfig:
     mlstm_proj_factor: int = 2
     mamba_expand: int = 1
     slstm_period: int = 8     # one sLSTM per this many blocks (xLSTM [7:1])
+    # Mamba2 mixer: heads (0 = the model's n_heads, Hymba's SSD heads),
+    # B/C groups (each read by mamba_heads / n_groups heads) and the causal
+    # depthwise conv window, with bias, over x|B|C (0 = none, Hymba).  A
+    # conv window also selects Mamba2's gate-then-norm over the inner width.
+    mamba_heads: int = 0
+    n_groups: int = 1
+    d_conv: int = 0
 
     def slstm_ffn_dim(self, d: int) -> int:
         return -(-(4 * d) // (3 * 64)) * 64  # ceil(4d/3) to a 64 multiple
@@ -90,6 +97,15 @@ class ModelConfig:
     # MoE expert parallelism: gspmd (auto) | shard_map (manual all_to_all)
     moe_impl: str = "gspmd"
     remat: str = "full"       # none | dots | full
+    # per-layer mixer ("mamba" | "attention"), as a published
+    # ``layer_types`` lists it; empty = one block kind for the whole stack
+    layer_types: Tuple[str, ...] = ()
+    norm_eps: float = 1e-6    # every RMSNorm's epsilon
+    position_embedding: str = "rope"   # rope | nope
+    attention_multiplier: Optional[float] = None  # score scale; None = hd^-0.5
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0   # on both branches of a pattern layer
+    logits_scaling: float = 1.0        # logits are divided by it
     notes: str = ""
 
     def __post_init__(self):
@@ -106,6 +122,8 @@ class ModelConfig:
 
     @property
     def block_kind(self) -> str:
+        if self.layer_types:
+            return "pattern"
         if self.family == "moe":
             return "moe"
         if self.family == "ssm":

@@ -1,5 +1,8 @@
 """Attention on the RedMulE engine: GQA (+qk-norm, sliding window) and MLA.
 
+GQA takes rotary positions or none (``cfg.position_embedding`` "nope"), and
+its score scale from ``cfg.attention_multiplier`` (default ``hd**-0.5``).
+
 Prefill/train uses a q-chunked online attention (flash-style in pure jnp) so
 32k-sequence score tensors are never materialized whole; on TPU the Pallas
 ``flash_attention`` kernel implements the same schedule.  Decode attends one
@@ -378,14 +381,15 @@ def gqa_attention(
     vv = vv.reshape(B, S, hkv, hd).transpose(0, 2, 1, 3)
 
     if cfg.qk_norm:
-        q = layers.rmsnorm(q, params["q_norm"])
-        kk = layers.rmsnorm(kk, params["k_norm"])
+        q = layers.rmsnorm(q, params["q_norm"], cfg.norm_eps)
+        kk = layers.rmsnorm(kk, params["k_norm"], cfg.norm_eps)
 
-    positions = (off[:, None] + jnp.arange(S)[None] if off.ndim == 1
-                 else off + jnp.arange(S))
-    cos, sin = layers.rope(positions, hd, cfg.rope_theta)
-    q = layers.apply_rope(q, cos, sin)
-    kk = layers.apply_rope(kk, cos, sin)
+    if cfg.position_embedding == "rope":
+        positions = (off[:, None] + jnp.arange(S)[None] if off.ndim == 1
+                     else off + jnp.arange(S))
+        cos, sin = layers.rope(positions, hd, cfg.rope_theta)
+        q = layers.apply_rope(q, cos, sin)
+        kk = layers.apply_rope(kk, cos, sin)
 
     if cache is not None:
         fp8 = prec.is_fp8(cache["k"].dtype)
@@ -440,8 +444,8 @@ def gqa_attention(
     o = chunked_attention(
         qg, k_all, v_all,
         q_offset=pos_offset, kv_valid=kv_valid, causal=True,
-        window=window, q_chunk=q_chunk, policy=policy,
-        kv_group_sizes=kv_group_sizes,
+        window=window, q_chunk=q_chunk, scale=cfg.attention_multiplier,
+        policy=policy, kv_group_sizes=kv_group_sizes,
     )
     o = o.reshape(B, hq, S, hd).transpose(0, 2, 1, 3).reshape(B, S, hq * hd)
     o = sharding.constrain(o, "batch", None, "heads")
@@ -481,7 +485,7 @@ def mla_attention(
 
     dkv = engine.matmul(x, params["wdkv"], policy=policy)  # (B, S, r + dr)
     ckv, kr = dkv[..., :r], dkv[..., r:]
-    ckv = layers.rmsnorm(ckv, params["kv_norm"])
+    ckv = layers.rmsnorm(ckv, params["kv_norm"], cfg.norm_eps)
 
     positions = (off[:, None] + jnp.arange(S)[None] if off.ndim == 1
                  else off + jnp.arange(S))

@@ -1,4 +1,4 @@
-"""SSM blocks: xLSTM (mLSTM + sLSTM) and Mamba2/SSD (for Hymba).
+"""SSM blocks: xLSTM (mLSTM + sLSTM) and Mamba2/SSD (Hymba, Granite-4.0-H).
 
 One *chunkwise linear-attention engine* serves both mLSTM and SSD: the
 recurrence ``S_t = exp(g_t) * S_{t-1} + k_t v_t^T`` is evaluated in chunks —
@@ -31,7 +31,10 @@ __all__ = [
     "mlstm_block",
     "slstm_schema",
     "slstm_block",
+    "mamba_dims",
     "mamba_schema",
+    "init_mamba_state",
+    "mamba_step",
     "mamba_mixer",
 ]
 
@@ -81,11 +84,12 @@ def linear_attention_step(
     return out, state
 
 
-def _per_head_rmsnorm(x: jax.Array, scale: jax.Array, H: int) -> jax.Array:
+def _per_head_rmsnorm(x: jax.Array, scale: jax.Array, H: int,
+                      eps: float) -> jax.Array:
     """Group-norm over each head's channels. x: (B, S, di), scale: (di,)."""
     B, S, di = x.shape
     xh = x.reshape(B, S, H, di // H)
-    xh = layers.rmsnorm(xh, jnp.ones((di // H,), x.dtype))
+    xh = layers.rmsnorm(xh, jnp.ones((di // H,), x.dtype), eps)
     return xh.reshape(B, S, di) * scale.astype(x.dtype)
 
 
@@ -142,7 +146,7 @@ def mlstm_block(
             q, k, v, log_g, chunk=cfg.ssm.chunk, state=state)
 
     o = o.transpose(0, 2, 1, 3).reshape(B, S, di).astype(x.dtype)
-    o = _per_head_rmsnorm(o, params["norm"], H)
+    o = _per_head_rmsnorm(o, params["norm"], H, cfg.norm_eps)
     o = o * jax.nn.silu(z)
     return engine.matmul(o, params["w_down"], policy=policy), state
 
@@ -201,61 +205,154 @@ def slstm_block(
     with engine.repeat(S):  # time scan: body traced once, runs S times
         state, hs = jax.lax.scan(step, state, jnp.moveaxis(wx, 1, 0))
     h = jnp.moveaxis(hs, 0, 1).reshape(B, S, d).astype(x.dtype)
-    h = layers.rmsnorm(h, params["norm"])
+    h = layers.rmsnorm(h, params["norm"], cfg.norm_eps)
     y = h + layers.mlp_glu(params["ffn"], h, act=cfg.act, policy=policy)
     return y, state
 
 
 # --------------------------------------------------------------------- #
-# Mamba2 / SSD mixer (Hymba's SSM heads)
+# Mamba2 / SSD mixer (Hymba's SSM heads, Granite-4.0-H's Mamba2 layers)
 # --------------------------------------------------------------------- #
+def mamba_dims(cfg) -> Tuple[int, int, int, int, int]:
+    """(inner width, heads, head width, groups, state width)."""
+    di = cfg.ssm.mamba_expand * cfg.d_model
+    H = cfg.ssm.mamba_heads or cfg.n_heads
+    return di, H, di // H, cfg.ssm.n_groups, cfg.ssm.state_dim
+
+
 def mamba_schema(cfg) -> Dict[str, Any]:
     d = cfg.d_model
-    di = cfg.ssm.mamba_expand * d
-    H, N = cfg.n_heads, cfg.ssm.state_dim
-    return {
+    di, H, _, G, N = mamba_dims(cfg)
+    s = {
         "w_xz": Param((d, 2 * di), ("embed", "ff")),
-        "w_bcdt": Param((d, 2 * N + H), ("embed", None)),
+        "w_bcdt": Param((d, 2 * G * N + H), ("embed", None)),
         "a_log": Param((H,), (None,), init="zeros"),
         "skip_d": Param((H,), (None,), init="ones"),
         "dt_bias": Param((H,), (None,), init="zeros"),
         "norm": Param((di,), (None,), init="ones"),
         "w_out": Param((di, d), ("ff", "embed")),
     }
+    K = cfg.ssm.d_conv
+    if K:
+        s["conv_w"] = Param((K, di + 2 * G * N), (None, None))
+        s["conv_b"] = Param((di + 2 * G * N,), (None,), init="zeros")
+    return s
+
+
+def init_mamba_state(cfg, batch: int) -> Dict[str, jax.Array]:
+    """One Mamba2 layer's decode state: the SSM state ``(B, H, P, N)`` and
+    the conv window's last ``d_conv - 1`` inputs ``(B, d_conv - 1, C)``,
+    both fp32 and zero at a sequence's start."""
+    di, H, P, G, N = mamba_dims(cfg)
+    return {
+        "ssm": jnp.zeros((batch, H, P, N), jnp.float32),
+        "conv": jnp.zeros((batch, cfg.ssm.d_conv - 1, di + 2 * G * N),
+                          jnp.float32),
+    }
+
+
+def mamba_step(state: jax.Array, c: jax.Array, b: jax.Array, v: jax.Array,
+               log_g: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One SSM decode step with the state held ``(B, H, P, N)``:
+    ``S' = exp(g) S + v b^T``, ``y = S' c``.
+
+    The state keeps N (the state width) on the lanes, so a head width P
+    under 128 is not padded to a tile's width.  The read-out is a
+    matrix-vector product bound by the state's bytes: this one elementwise
+    pass reads S once and writes S' once, where a GEMM dispatch would read
+    S' again (and widen c to a lane tile)."""
+    f32 = jnp.float32
+    state = (jnp.exp(log_g.astype(f32))[..., None, None] * state
+             + v.astype(f32)[..., :, None] * b.astype(f32)[..., None, :])
+    return jnp.sum(state * c.astype(f32)[..., None, :], axis=-1), state
+
+
+def _causal_conv(xbc: jax.Array, w: jax.Array, b: jax.Array,
+                 prev: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Depthwise causal conv, then SiLU.  xbc (B, S, C) fp32; w (K, C);
+    ``prev`` (B, K-1, C) the inputs before ``xbc[:, 0]``.  Returns the
+    activations and the last K-1 inputs (the next call's ``prev``)."""
+    K, S = w.shape[0], xbc.shape[1]
+    full = jnp.concatenate([prev, xbc], axis=1)          # (B, S+K-1, C)
+    w = w.astype(jnp.float32)
+    y = full[:, 0:S] * w[0]
+    for j in range(1, K):
+        y = y + full[:, j:j + S] * w[j]
+    return jax.nn.silu(y + b.astype(jnp.float32)), full[:, S:]
+
+
+def _per_group(m: jax.Array, H: int, G: int, N: int) -> jax.Array:
+    """(B, S, G*N) -> (B, H, S, N): head h reads group h // (H / G)."""
+    B, S, _ = m.shape
+    m = m.reshape(B, S, G, 1, N).transpose(0, 2, 3, 1, 4)
+    return jnp.broadcast_to(m, (B, G, H // G, S, N)).reshape(B, H, S, N)
 
 
 def mamba_mixer(
-    params, x, cfg, *, policy, state=None,
-) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """SSD: linear attention with q=C, k=B, v=dt*x, decay=exp(-exp(A)dt)."""
+    params, x, cfg, *, policy, state=None, carry: bool = True,
+) -> Tuple[jax.Array, Any]:
+    """Mamba2 / SSD: linear attention with q=C, k=B, v=dt*x and decay
+    exp(dt*A), A = -exp(a_log), plus the D skip.  Returns ``(out, state)``.
+
+    x: (B, S, d).  ``state`` is the decode state: the SSM state
+    ``(B, H, P, N)``, or with a conv window the dict ``{"ssm", "conv"}`` of
+    :func:`init_mamba_state`.  S == 1 with a state is one decode step
+    (:func:`mamba_step` and the conv window's shift).  A longer x runs the
+    Engine's chunked sweep from ``state``, or from zero when ``carry`` is
+    false: that is a prompt's prefill, and it runs the linear-attention
+    kernel.
+
+    With ``cfg.ssm.d_conv`` set (Granite-4.0-H) x|B|C pass a causal
+    depthwise conv and SiLU first, and the output is gated then normed over
+    the whole inner width (Mamba2's ``RMSNormGated``); without it (Hymba)
+    each head's output is normed, then gated."""
     B_, S, d = x.shape
-    H, N = cfg.n_heads, cfg.ssm.state_dim
-    di = cfg.ssm.mamba_expand * d
-    P = di // H
+    di, H, P, G, N = mamba_dims(cfg)
+    K = cfg.ssm.d_conv
+    ssm_state, conv = (state["ssm"], state["conv"]) if K and state is not None \
+        else (state, None)
 
     xz = engine.matmul(x, params["w_xz"], policy=policy)
     xin, z = jnp.split(xz, 2, axis=-1)
-    bcdt = engine.matmul(x, params["w_bcdt"], policy=_F32)   # (B, S, 2N + H)
-    bmat, cmat, dt = jnp.split(bcdt, [N, 2 * N], axis=-1)
+    bcdt = engine.matmul(x, params["w_bcdt"], policy=_F32)   # (B, S, 2GN + H)
+    bc, dt = bcdt[..., :2 * G * N], bcdt[..., 2 * G * N:]
+    if K:
+        prev = (conv if carry and conv is not None
+                else jnp.zeros((B_, K - 1, di + 2 * G * N), jnp.float32))
+        xbc, conv = _causal_conv(
+            jnp.concatenate([xin.astype(jnp.float32), bc], axis=-1),
+            params["conv_w"], params["conv_b"], prev)
+        xin, bc = xbc[..., :di], xbc[..., di:]
+    bmat, cmat = bc[..., :G * N], bc[..., G * N:]
     dt = jax.nn.softplus(dt + params["dt_bias"].astype(jnp.float32))  # (B,S,H)
     a = -jnp.exp(params["a_log"].astype(jnp.float32))
     log_g = (dt * a[None, None]).transpose(0, 2, 1)      # (B, H, S) <= 0
 
     v = xin.reshape(B_, S, H, P).transpose(0, 2, 1, 3)   # (B, H, S, P)
     v_in = v * dt.transpose(0, 2, 1)[..., None].astype(v.dtype)
-    q = jnp.broadcast_to(cmat[:, None], (B_, H, S, N))
-    k = jnp.broadcast_to(bmat[:, None], (B_, H, S, N))
+    q = _per_group(cmat, H, G, N)
+    k = _per_group(bmat, H, G, N)
 
-    if S == 1 and state is not None:
-        o, state = linear_attention_step(
-            state, q[:, :, 0], k[:, :, 0], v_in[:, :, 0], log_g[:, :, 0])
+    if S == 1 and ssm_state is not None:
+        o, ssm_state = mamba_step(ssm_state, q[:, :, 0], k[:, :, 0],
+                                  v_in[:, :, 0], log_g[:, :, 0])
         o = o[:, :, None]
     else:
-        o, state = chunked_linear_attention(
-            q, k, v_in, log_g, chunk=cfg.ssm.chunk, state=state)
+        # the Engine's sweep holds the state (N, P)
+        st = ssm_state.swapaxes(-1, -2) if carry and ssm_state is not None \
+            else None
+        o, st = chunked_linear_attention(
+            q, k, v_in, log_g, chunk=cfg.ssm.chunk, state=st)
+        ssm_state = st.swapaxes(-1, -2)
 
     o = o + v.astype(jnp.float32) * params["skip_d"].astype(jnp.float32)[None, :, None, None]
-    o = o.transpose(0, 2, 1, 3).reshape(B_, S, di).astype(x.dtype)
-    o = _per_head_rmsnorm(o, params["norm"], H)
-    o = o * jax.nn.silu(z)
-    return engine.matmul(o, params["w_out"], policy=policy), state
+    o = o.transpose(0, 2, 1, 3).reshape(B_, S, di)
+    if K:
+        o = o * jax.nn.silu(z.astype(jnp.float32))
+        o = layers.rmsnorm(o, params["norm"], cfg.norm_eps).astype(x.dtype)
+    else:
+        o = _per_head_rmsnorm(o.astype(x.dtype), params["norm"], H,
+                              cfg.norm_eps)
+        o = o * jax.nn.silu(z)
+    out = engine.matmul(o, params["w_out"], policy=policy)
+    return out, ({"ssm": ssm_state, "conv": conv} if K else ssm_state)

@@ -8,7 +8,11 @@ Heterogeneous stacks are expressed structurally:
   * xlstm           — scanned super-blocks of (7 mLSTM + 1 sLSTM);
   * hymba           — one scanned stack of parallel attn+SSM blocks with a
                       per-layer sliding-window array (full-attn layers get a
-                      2^30 window).
+                      2^30 window);
+  * pattern         — a published ``layer_types`` list of Mamba2 and
+                      attention layers (Granite-4.0-H): scanned over whole
+                      periods of the pattern, the period's layers unrolled in
+                      its order, so the compile is one period's size.
 
 Public API: ``schema / init_params / param_specs / abstract_params /
 forward / loss_fn / serve_step / init_cache / count_params``.
@@ -29,7 +33,7 @@ from repro.runtime import sharding
 __all__ = [
     "schema", "init_params", "param_specs", "abstract_params",
     "forward", "loss_fn", "serve_step", "init_cache", "count_params",
-    "window_array",
+    "window_array", "layer_period",
 ]
 
 BIG_WINDOW = 1 << 30
@@ -89,6 +93,37 @@ def _hymba_block_schema(cfg) -> Dict[str, Any]:
     }
 
 
+def layer_period(cfg) -> Tuple[str, ...]:
+    """The shortest repeating unit of ``cfg.layer_types``."""
+    t = tuple(cfg.layer_types)
+    for p in range(1, len(t) + 1):
+        if len(t) % p == 0 and t == t[:p] * (len(t) // p):
+            return t[:p]
+    raise ValueError("empty layer_types")
+
+
+def _pattern_schemas(cfg) -> Dict[str, Dict[str, Any]]:
+    return {
+        "mamba": {
+            "ln1": _norm_param(cfg),
+            "mamba": ssm.mamba_schema(cfg),
+            "ln2": _norm_param(cfg),
+            "mlp": _mlp_schema(cfg, cfg.d_ff),
+        },
+        "attention": _attn_block_schema(cfg),
+    }
+
+
+def _pattern_schema(cfg) -> Dict[str, Any]:
+    """Per layer kind, its layers stacked (periods, layers of the kind in
+    one period)."""
+    period = layer_period(cfg)
+    n = cfg.n_layers // len(period)
+    return {kind: layers.stack_schema(
+                layers.stack_schema(one, period.count(kind), None), n)
+            for kind, one in _pattern_schemas(cfg).items() if kind in period}
+
+
 def _xlstm_super_schema(cfg) -> Dict[str, Any]:
     n_m = cfg.ssm.slstm_period - 1
     m_block = {"ln": _norm_param(cfg), "cell": ssm.mlstm_schema(cfg)}
@@ -118,6 +153,8 @@ def schema(cfg) -> Dict[str, Any]:
         s["layers"] = layers.stack_schema(_moe_block_schema(cfg), cfg.n_layers - nd)
     elif kind == "hymba":
         s["layers"] = layers.stack_schema(_hymba_block_schema(cfg), cfg.n_layers)
+    elif kind == "pattern":
+        s["layers"] = _pattern_schema(cfg)
     elif kind == "xlstm":
         n_super, rem = divmod(cfg.n_layers, cfg.ssm.slstm_period)
         assert rem == 0, f"n_layers {cfg.n_layers} % period {cfg.ssm.slstm_period}"
@@ -180,7 +217,7 @@ def window_array(cfg) -> Optional[jax.Array]:
 def _norm(cfg, x, scale):
     if cfg.norm == "layernorm":
         return layers.layernorm(x, scale)
-    return layers.rmsnorm(x, scale)
+    return layers.rmsnorm(x, scale, cfg.norm_eps)
 
 
 def _run_attn(cfg, p, h, *, pos, cache, window, policy, kv_group_sizes=None):
@@ -233,6 +270,78 @@ def _hymba_block(p, h, cfg, *, pos, cache, window, policy):
     mlp_out = layers.mlp_glu(p["mlp"], _norm(cfg, h, p["ln2"]), act=cfg.act, policy=policy)
     new_cache = None if cache is None else {"attn": attn_cache, "ssm": ssm_state}
     return h + mlp_out, new_cache, {}
+
+
+def _pattern_layer(kind, p, h, cfg, *, pos, cache, policy, kv_group_sizes,
+                   fresh):
+    """One layer of a pattern stack: its mixer (Mamba2 or NoPE GQA) and
+    MLP, each branch scaled by ``residual_multiplier``.  A parked slot's
+    Mamba state runs on unread; admission overwrites it whole
+    (``kv_cache.insert_slot``), so every request starts from its prefill's
+    state."""
+    x = _norm(cfg, h, p["ln1"])
+    if kind == "mamba":
+        with jax.named_scope("mamba_mixer"):
+            m, new_cache = ssm.mamba_mixer(
+                p["mamba"], x, cfg, policy=policy, state=cache,
+                carry=not fresh)
+            if cache is None:
+                new_cache = None
+    else:
+        with jax.named_scope("attention"):
+            m, new_cache = attention.gqa_attention(
+                p["attn"], x, cfg, pos_offset=pos, cache=cache, window=None,
+                policy=policy, q_chunk=cfg.q_chunk,
+                kv_group_sizes=kv_group_sizes)
+    r = cfg.residual_multiplier
+    h = h + r * m
+    mlp = layers.mlp_glu(p["mlp"], _norm(cfg, h, p["ln2"]), act=cfg.act,
+                         policy=policy)
+    return h + r * mlp, new_cache
+
+
+def _pattern_stack(cfg, params, h, cache, *, pos, policy, kv_group_sizes,
+                   fresh):
+    """Scan over whole periods of the layer pattern.  The cache rides the
+    scan's carry and each layer's slice of it is updated in place."""
+    period = layer_period(cfg)
+    n = cfg.n_layers // len(period)
+    has_cache = cache is not None
+
+    def at(a, i, j):
+        """Layer (period i, index j) of a stacked leaf."""
+        start = (i, j) + (0,) * (a.ndim - 2)
+        return jax.lax.dynamic_slice(a, start, (1, 1) + a.shape[2:])[0, 0]
+
+    def put(a, u, i, j):
+        start = (i, j) + (0,) * (a.ndim - 2)
+        return jax.lax.dynamic_update_slice(
+            a, u.astype(a.dtype)[None, None], start)
+
+    def body(carry, i):
+        h, c = carry
+        h = sharding.constrain(h, "batch", "seq_sharded", None)
+        seen = {k: 0 for k in params}
+        for kind in period:
+            j = seen[kind]
+            seen[kind] += 1
+            lc = (jax.tree.map(lambda a: at(a, i, j), c[kind]) if has_cache
+                  else None)
+            h, lc = _pattern_layer(
+                kind, jax.tree.map(lambda a: at(a, i, j), params[kind]), h,
+                cfg, pos=pos, cache=lc, policy=policy,
+                kv_group_sizes=kv_group_sizes, fresh=fresh)
+            if has_cache:
+                c = dict(c, **{kind: jax.tree.map(
+                    lambda a, u: put(a, u, i, j), c[kind], lc)})
+        return (h, c), 0
+
+    # the weights are the loop's invariants and each layer slices its own,
+    # so no period's weights are copied out whole
+    with engine.repeat(n):  # body traced once, runs n periods
+        (h, cache), _ = jax.lax.scan(
+            _remat(cfg, body), (h, cache if has_cache else 0), jnp.arange(n))
+    return h, (cache if has_cache else None)
 
 
 def _xlstm_super_block(p, h, cfg, *, cache, policy):
@@ -331,12 +440,16 @@ def forward(
     kv_group_sizes: Optional[Any] = None,
 ) -> Tuple[jax.Array, Optional[Dict[str, Any]], Dict[str, jax.Array]]:
     policy = cfg.policy
+    # a prompt prefilled from position 0: recurrent layers start from zero
+    fresh = isinstance(pos, int) and pos == 0
     pos = jnp.asarray(pos, jnp.int32)
 
     if "embeddings" in batch:
         h = batch["embeddings"].astype(policy.compute_dtype)
     else:
         h = params["embed"][batch["inputs"]].astype(policy.compute_dtype)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
     h = sharding.constrain(h, "batch", "seq_sharded", None)
 
     kind = cfg.block_kind
@@ -370,6 +483,13 @@ def forward(
             cfg, fn, params["layers"], h,
             None if cache is None else cache["layers"], window_array(cfg))
         new_cache["layers"] = nc
+    elif kind == "pattern":
+        h, nc = _pattern_stack(
+            cfg, params["layers"], h,
+            None if cache is None else cache["layers"], pos=pos,
+            policy=policy, kv_group_sizes=kv_group_sizes, fresh=fresh)
+        new_cache["layers"] = nc
+        aux = {}
     elif kind == "xlstm":
         fn = lambda lp, hh, *, cache, window: _xlstm_super_block(
             lp, hh, cfg, cache=cache, policy=policy)
@@ -385,10 +505,16 @@ def forward(
     h = _norm(cfg, h, params["final_norm"])
     if not head:
         return h, (new_cache if cache is not None else None), aux
-    w_head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = engine.matmul(h, w_head, policy=policy)
-    logits = sharding.constrain(logits, "batch", "seq_sharded", "vocab")
+    logits = _head(params, cfg, h)
     return logits, (new_cache if cache is not None else None), aux
+
+
+def _head(params, cfg, h):
+    w_head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = engine.matmul(h, w_head, policy=cfg.policy)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return sharding.constrain(logits, "batch", "seq_sharded", "vocab")
 
 
 def _chunked_ce(params, cfg, h, labels) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -397,8 +523,6 @@ def _chunked_ce(params, cfg, h, labels) -> Tuple[jax.Array, Dict[str, jax.Array]
     Scans batch-row chunks; each chunk's vocab GEMM + log-softmax is inside
     a jax.checkpoint so backward recomputes the chunk logits instead of
     storing them.  Peak extra memory: one chunk of fp32 logits."""
-    policy = cfg.policy
-    w_head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     B = h.shape[0]
     c = max(1, min(cfg.ce_chunk, B))
     n = -(-B // c)
@@ -410,9 +534,7 @@ def _chunked_ce(params, cfg, h, labels) -> Tuple[jax.Array, Dict[str, jax.Array]
 
     @jax.checkpoint
     def chunk(h_c, y_c):
-        logits = engine.matmul(h_c, w_head, policy=policy)
-        logits = sharding.constrain(logits, "batch", "seq_sharded", "vocab")
-        lf = logits.astype(jnp.float32)
+        lf = _head(params, cfg, h_c).astype(jnp.float32)
         lse = jax.nn.logsumexp(lf, axis=-1)
         gold = jnp.take_along_axis(
             lf, jnp.maximum(y_c, 0)[..., None], axis=-1)[..., 0]
@@ -507,6 +629,15 @@ def cache_axes(cfg, storage_dtype=None):
     if kind == "hymba":
         one = {"attn": gqa, "ssm": ("batch", None, None, None)}
         return {"layers": stackax(one)}
+    if kind == "pattern":
+        period = layer_period(cfg)
+        kinds = {"mamba": {"ssm": ("batch", None, None, None),
+                           "conv": ("batch", None, None)},
+                 "attention": gqa}
+        return {"layers": {
+            k: jax.tree.map(lambda ax: ("layers", None, *ax), one,
+                            is_leaf=lambda x: isinstance(x, tuple))
+            for k, one in kinds.items() if k in period}}
     if kind == "xlstm":
         one = {
             "mlstm": (None, "batch", None, None, None),
@@ -523,12 +654,16 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None, storage_dtype=None):
     k/v tensors narrow with per-head delayed-scaling leaves alongside —
     the RedMulE mixed-precision trade (narrow storage, wide datapath)
     applied to the KV cache.  Only attention caches quantize; SSM/xLSTM
-    state stays wide (attn/moe block kinds only)."""
+    state stays wide (attn/moe/pattern block kinds only).  A pattern
+    stack's cache holds, per layer kind, its layers stacked (periods,
+    layers of the kind in a period): KV rows for attention, fp32 SSM
+    state and conv window for Mamba2."""
     dtype = dtype or cfg.policy.compute_dtype
     kind = cfg.block_kind
-    if storage_dtype is not None and kind not in ("attn", "moe"):
+    if storage_dtype is not None and kind not in ("attn", "moe", "pattern"):
         raise ValueError(
-            f"FP8 cache storage supports attn/moe block kinds, not {kind!r}")
+            f"FP8 cache storage supports attn/moe/pattern block kinds, "
+            f"not {kind!r}")
 
     def stack(tree, n):
         return jax.tree.map(lambda x: jnp.broadcast_to(x[None], (n, *x.shape)), tree)
@@ -541,12 +676,21 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None, storage_dtype=None):
         one = (attention.init_mla_cache if cfg.mla else attention.init_gqa_cache)(
             cfg, batch, max_len, dtype, storage_dtype)
         return {"layer0": one, "layers": stack(one, cfg.n_layers - cfg.moe.first_dense)}
+    if kind == "pattern":
+        period = layer_period(cfg)
+        n = cfg.n_layers // len(period)
+        kinds = {"attention": lambda: attention.init_gqa_cache(
+                     cfg, batch, max_len, dtype, storage_dtype),
+                 "mamba": lambda: ssm.init_mamba_state(cfg, batch)}
+        return {"layers": {
+            k: stack(stack(make(), period.count(k)), n)
+            for k, make in kinds.items() if k in period}}
     if kind == "hymba":
         di = cfg.ssm.mamba_expand * cfg.d_model
         one = {
             "attn": attention.init_gqa_cache(cfg, batch, max_len, dtype),
             "ssm": jnp.zeros(
-                (batch, cfg.n_heads, cfg.ssm.state_dim, di // cfg.n_heads),
+                (batch, cfg.n_heads, di // cfg.n_heads, cfg.ssm.state_dim),
                 jnp.float32),
         }
         return {"layers": stack(one, cfg.n_layers)}

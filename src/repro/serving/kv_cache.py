@@ -44,7 +44,7 @@ __all__ = [
     "is_fp8_cache", "insert_slot", "n_cache_layers", "token_elems",
     "n_scale_elems", "storage_width", "decode_step_kv_bytes",
     "cache_size_bytes", "scale_health", "iter_kv_leaves",
-    "slot_checksum", "corrupt_slot_rows",
+    "slot_checksum", "corrupt_slot_rows", "state_bytes",
 ]
 
 
@@ -52,8 +52,9 @@ __all__ = [
 # Slot admission
 # --------------------------------------------------------------------- #
 def is_fp8_cache(cache: CacheTree) -> bool:
-    sub = cache.get("layers", cache.get("layer0", {}))
-    return "k_scale" in sub or "ckv_scale" in sub
+    if "k_scale" in cache or "ckv_scale" in cache:
+        return True
+    return any(is_fp8_cache(v) for v in cache.values() if isinstance(v, dict))
 
 
 def _gqa_bcast(scale: jax.Array) -> jax.Array:
@@ -97,14 +98,21 @@ def _insert_leaf(pool_sub, single_sub, name, slot, dtype, bcast, tail, reduce_of
     return {name: q, f"{name}_scale": sc2}
 
 
+# Recurrent-state leaves (Mamba2 layers of a pattern stack): name -> the
+# number of trailing axes from the batch axis on, as for KV's ``tail``.
+STATE_TAILS = {"ssm": 4, "conv": 3}   # (B, H, P, N), (B, K-1, C)
+
+
 def insert_slot(pool: CacheTree, single: CacheTree, slot,
                 dtype=jnp.float16) -> CacheTree:
     """Write a single-request cache (batch == 1) into ``slot`` of the pool.
 
     ``slot`` may be traced (one jit trace serves every slot).  Supports the
-    attn/moe cache trees (gqa and MLA subtrees, stacked or not); FP8 pools
-    dequantize both sides to ``dtype``, merge, refresh the pool's delayed
-    scales with the merged amax, and requantize under the ratcheted scale.
+    attn/moe cache trees (gqa and MLA subtrees, stacked or not) and a
+    pattern stack's per-kind subtrees, whose Mamba2 state (``ssm``,
+    ``conv``) is written as it is.  FP8 pools dequantize both sides to
+    ``dtype``, merge, refresh the pool's delayed scales with the merged
+    amax, and requantize under the ratcheted scale.
     """
     def sub(ps, ss):
         if "k" in ps:
@@ -119,35 +127,46 @@ def insert_slot(pool: CacheTree, single: CacheTree, slot,
                 out.update(_insert_leaf(
                     ps, ss, name, slot, dtype, _mla_bcast, 3, _mla_reduce))
             return out
+        if "ssm" in ps:
+            return {name: jax.lax.dynamic_update_slice_in_dim(
+                        ps[name], ss[name].astype(ps[name].dtype), slot,
+                        axis=ps[name].ndim - STATE_TAILS[name])
+                    for name in ps}
+        if all(isinstance(v, dict) for v in ps.values()):
+            return {key: sub(ps[key], ss[key]) for key in ps}
         raise ValueError(
-            "slot insertion supports attn/moe (gqa/MLA) caches only")
+            "slot insertion supports attn/moe (gqa/MLA) and pattern caches")
 
-    return {key: sub(pool[key], single[key]) for key in pool}
+    return sub(pool, single)
 
 
 # --------------------------------------------------------------------- #
 # Slot integrity: checksums + deterministic corruption
 # --------------------------------------------------------------------- #
-def iter_kv_leaves(cache: CacheTree) -> Iterator[Tuple[str, str, Any, int]]:
-    """Yield ``(key, name, leaf, batch_axis)`` for every KV data leaf.
+def iter_kv_leaves(cache: CacheTree, _path: Tuple[str, ...] = ()
+                   ) -> Iterator[Tuple[Tuple[str, ...], str, Any, int]]:
+    """Yield ``(path, name, leaf, batch_axis)`` for every KV data leaf.
 
     Covers the gqa (``k``/``v``, trailing ``(B, Hkv, T, hd)``) and MLA
-    (``ckv``/``kr``, trailing ``(B, T, r)``) subtrees, stacked or not;
-    scale-state leaves are skipped.  The sequence axis is always the
-    second-to-last axis of the leaf.
+    (``ckv``/``kr``, trailing ``(B, T, r)``) subtrees, stacked or not and
+    at any depth (``path`` is the keys down to the subtree); scale-state
+    and recurrent-state leaves are skipped.  The sequence axis is always
+    the second-to-last axis of the leaf.
     """
     for key, sub in cache.items():
         if not isinstance(sub, dict):
             continue
+        path = _path + (key,)
         if "k" in sub:
             names, tail = ("k", "v"), 4
         elif "ckv" in sub:
             names, tail = ("ckv", "kr"), 3
         else:
+            yield from iter_kv_leaves(sub, path)
             continue
         for name in names:
             leaf = sub[name]
-            yield key, name, leaf, leaf.ndim - tail
+            yield path, name, leaf, leaf.ndim - tail
 
 
 def slot_checksum(cache: CacheTree, slot: int, length: int) -> int:
@@ -193,16 +212,15 @@ def corrupt_slot_rows(cache: CacheTree, slot: int,
         slot_view[tuple(row_sel)] = flipped
         return jnp.asarray(arr)
 
-    out: CacheTree = {}
-    flipped_leaves = {(k, n): flip(leaf, bax)
-                      for k, n, leaf, bax in iter_kv_leaves(cache)}
-    for key, sub in cache.items():
-        if not isinstance(sub, dict):
-            out[key] = sub
-            continue
-        out[key] = {name: flipped_leaves.get((key, name), leaf)
-                    for name, leaf in sub.items()}
-    return out
+    flipped = {path + (n,): flip(leaf, bax)
+               for path, n, leaf, bax in iter_kv_leaves(cache)}
+
+    def rebuild(node, path):
+        if not isinstance(node, dict):
+            return flipped.get(path, node)
+        return {k: rebuild(v, path + (k,)) for k, v in node.items()}
+
+    return rebuild(cache, ())
 
 
 # --------------------------------------------------------------------- #
@@ -214,8 +232,22 @@ def n_cache_layers(cfg) -> int:
         return cfg.n_layers
     if cfg.block_kind == "moe":
         return 1 + (cfg.n_layers - cfg.moe.first_dense)
-    raise ValueError(
-        f"serving byte accounting supports attn/moe, not {cfg.block_kind!r}")
+    if cfg.block_kind == "pattern":
+        return sum(1 for t in cfg.layer_types if t == "attention")
+    raise ValueError(f"serving byte accounting supports attn/moe/pattern, "
+                     f"not {cfg.block_kind!r}")
+
+
+def state_bytes(cfg) -> int:
+    """One slot's recurrent state (fp32 SSM state and conv window of every
+    Mamba2 layer), resident; a decode step reads and writes it once."""
+    if cfg.block_kind != "pattern":
+        return 0
+    from repro.models import ssm
+
+    di, H, P, G, N = ssm.mamba_dims(cfg)
+    per = H * N * P + (cfg.ssm.d_conv - 1) * (di + 2 * G * N)
+    return 4 * per * sum(1 for t in cfg.layer_types if t == "mamba")
 
 
 def token_elems(cfg) -> int:
@@ -261,9 +293,10 @@ def decode_step_kv_bytes(cfg, lengths: Sequence[int],
 
 def cache_size_bytes(cfg, batch: int, max_len: int,
                      storage_dtype: Optional[str] = None) -> int:
-    """Resident bytes of ``init_cache``'s output (data + scale-state leaves)."""
+    """Resident bytes of ``init_cache``'s output (data + scale-state leaves
+    + recurrent state)."""
     w = storage_width(cfg, storage_dtype)
-    data = w * token_elems(cfg) * batch * max_len
+    data = w * token_elems(cfg) * batch * max_len + state_bytes(cfg) * batch
     if storage_dtype is None:
         return data
     # scale + amax_history + overflow_count per quantized tensor (4 B each)

@@ -151,10 +151,10 @@ class Scheduler:
     def __init__(self, params, cfg, scfg: SchedulerConfig,
                  rules: Optional[sharding.Rules] = None,
                  injector=None):
-        if cfg.block_kind not in ("attn", "moe"):
+        if cfg.block_kind not in ("attn", "moe", "pattern"):
             raise ValueError(
-                f"the serving scheduler drives attn/moe decode caches, "
-                f"not {cfg.block_kind!r}")
+                f"the serving scheduler drives attn/moe/pattern decode "
+                f"caches, not {cfg.block_kind!r}")
         if scfg.n_slots < 1:
             raise ValueError("need at least one decode slot")
         if (injector is not None and injector.mode == "kv_corrupt"
@@ -172,6 +172,10 @@ class Scheduler:
         self.cache = transformer.init_cache(
             cfg, scfg.n_slots, scfg.max_len, dtype=self.compute_dtype,
             storage_dtype=scfg.storage_dtype)
+        # recurrent (Mamba2) state beside the KV rows: what the spans
+        # report, and how a slot is rebuilt
+        self.ssm_layers = sum(1 for t in cfg.layer_types if t == "mamba")
+        self.slot_state_bytes = kv_cache.state_bytes(cfg)
         self.slots: List[Optional[_Slot]] = [None] * scfg.n_slots
         self.pending: List[Request] = []       # submitted, arrival in future
         self.queue: deque = deque()            # admitted, waiting for a slot
@@ -373,7 +377,8 @@ class Scheduler:
             res.queue_s = time.perf_counter() - res.submitted_s
             prompt_np = np.asarray(r.prompt, np.int32)
             with _span(SPAN_PREFILL, rid=r.rid, plen=len(prompt_np),
-                       queued_ms=1e3 * res.queue_s):
+                       queued_ms=1e3 * res.queue_s,
+                       ssm_layers=self.ssm_layers):
                 prompt = jnp.asarray(prompt_np)[None]
                 logits, single = self._guarded_prefill(prompt, r.rid)
                 self.cache = self._insert(self.cache, single, jnp.int32(slot))
@@ -440,30 +445,37 @@ class Scheduler:
 
         Re-prefills ``prompt + emitted[:fed]`` — exactly the tokens whose
         KV the slot holds (rows valid ``[0, pos)``, ``pos == P + fed``) —
-        which reproduces the decode-built cache bitwise on FP16.  With
-        ``rerun_decode`` the poisoned decode step is replayed batch-1
-        (feed ``last_token`` at ``pos``) and the recovered logits row is
-        returned to replace the poisoned one; without it (checksum audit,
-        which fires *before* the corrupt rows are ever read) the rebuilt
-        cache alone restores the invariant.  The virtual clock does not
-        advance — recovery overlaps the pool and is billed as waste
-        slot-ticks by the caller."""
+        which reproduces the decode-built cache bitwise on FP16.  A cache
+        with recurrent state (Mamba2 layers) re-prefills the prompt alone
+        and replays the ``fed`` decode steps batch-1: the chunked prefill
+        sums the recurrence in another order than the steps that built the
+        slot, and the replay reproduces their state, conv window and KV
+        rows bitwise.  With ``rerun_decode`` the poisoned decode step is
+        replayed batch-1 (feed ``last_token`` at ``pos``) and the recovered
+        logits row is returned to replace the poisoned one; without it
+        (checksum audit, which fires *before* the corrupt rows are ever
+        read) the rebuilt cache alone restores the invariant.  The virtual
+        clock does not advance — recovery overlaps the pool and is billed
+        as waste slot-ticks by the caller."""
         with _span(SPAN_RECOVER, rid=s.rid):
             res = self.results[s.rid]
-            absorbed = np.concatenate(
-                [np.asarray(s.prompt, np.int32),
-                 np.asarray(res.tokens[:s.fed], np.int32)])
-            assert absorbed.shape[0] == s.pos, "slot rows out of sync"
-            seq = jnp.asarray(absorbed)[None]
-            _, single = self._prefill_fn(seq.shape[1], recover=True)(
-                self.params, seq)
-            row = None
+            fed = np.asarray(res.tokens[:s.fed], np.int32)
+            prompt = np.asarray(s.prompt, np.int32)
+            assert len(prompt) + len(fed) == s.pos, "slot rows out of sync"
+            replay = list(fed) if self.ssm_layers else []
+            seq = prompt if self.ssm_layers else np.concatenate([prompt, fed])
+            _, single = self._prefill_fn(len(seq), recover=True)(
+                self.params, jnp.asarray(seq)[None])
             if rerun_decode:
+                replay.append(s.last_token)
+            p, row = len(seq), None     # the next row the replay writes
+            for tok in replay:
                 logits1, single = self._recover_decode(
-                    self.params, single,
-                    jnp.asarray([[s.last_token]], np.int32),
-                    jnp.asarray([s.pos], np.int32),
-                    jnp.asarray([s.pos + 1], np.int32))
+                    self.params, single, jnp.asarray([[tok]], np.int32),
+                    jnp.asarray([p], np.int32),
+                    jnp.asarray([p + 1], np.int32))
+                p += 1
+            if rerun_decode:
                 row = np.asarray(logits1[0])
             self.cache = self._recover_insert(
                 self.cache, single, jnp.int32(slot))
@@ -549,7 +561,9 @@ class Scheduler:
                 # the last emitted token's KV was absorbed this step: the
                 # cache is consistent with the emitted sequence at eviction
                 res.finish_tick = self.clock
-                res.final_logits = logits[i]
+                # a copy: a view would keep the step's whole (slots, vocab)
+                # host array alive for as long as the result is held
+                res.final_logits = logits[i].copy()
                 res.status = "finished"
                 self.goodput.on_finish(len(res.tokens))
                 self.trace.append(("finish", self.clock, s.rid, i))
@@ -585,8 +599,10 @@ class Scheduler:
             self._admission(self._shed)
             active = len(self._active())
             if active:
+                ssm_slots = active if self.ssm_layers else 0
                 with _span(SPAN_DECODE, active=active,
-                           slots=self.scfg.n_slots):
+                           slots=self.scfg.n_slots, ssm_slots=ssm_slots,
+                           state_bytes=ssm_slots * self.slot_state_bytes):
                     self._decode_once()
                 return True
             if self.pending:  # idle until the next arrival

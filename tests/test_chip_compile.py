@@ -118,6 +118,20 @@ def test_chunked_linear_attention_compiles(one_chip, chunk):
     assert "tpu_custom_call" in text
 
 
+def test_granite_ssd_kernel_compiles(one_chip):
+    # granite-4.0-h-micro's Mamba2 prefill: 64 heads, fp32 C/B of state
+    # width 128 and dt x of head width 64, chunk 256
+    BH, S, dk, dv = 64, 512, 128, 64
+    text = _compile(
+        lambda q, k, v, g: chunked_linear_attention_pallas(
+            q, k, v, g, chunk=256),
+        _sds(one_chip, (BH, S, dk), jnp.float32),
+        _sds(one_chip, (BH, S, dk), jnp.float32),
+        _sds(one_chip, (BH, S, dv), jnp.float32),
+        _sds(one_chip, (BH, S), jnp.float32))
+    assert "redmule_chunked_linear_attention" in text
+
+
 @pytest.mark.parametrize("policy", ["tpu_bf16", "fp32"])
 def test_engine_linear_and_grad_compile(one_chip, policy):
     # forward plus the custom-VJP backward through the Engine's registry

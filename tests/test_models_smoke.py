@@ -123,6 +123,7 @@ def test_full_config_parameter_counts():
         "xlstm-1.3b": (1.6e9, 2.4e9),
         "hymba-1.5b": (1.2e9, 2.0e9),
         "pixtral-12b": (11.0e9, 13.5e9),  # backbone only (ViT stubbed)
+        "granite-4.0-h-micro": (3.1e9, 3.3e9),  # 3.19 B, tied embeddings
     }
     for arch, (lo, hi) in expect.items():
         n = configs.get(arch).param_count()
@@ -130,7 +131,7 @@ def test_full_config_parameter_counts():
 
 
 def test_cells_assignment():
-    """40 cells total; long_500k only for sub-quadratic families."""
+    """44 cells total; long_500k only for sub-quadratic families."""
     total = 0
     for arch in configs.ARCH_IDS:
         cfg = configs.get(arch)
@@ -143,4 +144,4 @@ def test_cells_assignment():
                     assert skip is None
                 else:
                     assert skip is not None
-    assert total == 40
+    assert total == 44

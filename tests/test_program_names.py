@@ -1,9 +1,10 @@
 """The names of the compiled programs that profiler traces are read by.
 
 A trace names each device program after its HLO module (``jit__decode``,
-``jit_pre``, ``jit_step``); the benchmark's readers of decode, prefill and
-training time find the programs by these names, so a refactor that renames
-one has to fail here rather than silence them.
+``jit_pre``, ``jit_step``), and each Pallas kernel after its ``name``; the
+benchmark's readers of decode, prefill and training time and of the SSD
+kernel's roofline find them by these names, so a refactor that renames one
+has to fail here rather than silence them.
 """
 
 import re
@@ -53,3 +54,24 @@ def test_train_step_name(yi):
     step = jax.jit(T.build_train_step(cfg, opt, rules=None),
                    donate_argnums=(0,))
     assert hlo_module(step.lower(state, batch)) == "jit_step"
+
+
+def test_ssd_kernel_name():
+    """The Mamba2 prefill's linear-attention kernel, as a trace names it
+    (``ssd_roofline.prefill`` reads it)."""
+    from repro.kernels.chunked_linear_attention import \
+        chunked_linear_attention_pallas
+
+    BH, S, dk, dv = 2, 256, 128, 64
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, g: chunked_linear_attention_pallas(
+        q, k, v, g, chunk=128))(sds(BH, S, dk), sds(BH, S, dk),
+                                sds(BH, S, dv), sds(BH, S))
+    def calls(jx):
+        for e in jx.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["name"]
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from calls(sub)
+
+    assert list(calls(jaxpr.jaxpr)) == ["redmule_chunked_linear_attention"]

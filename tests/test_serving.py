@@ -184,6 +184,17 @@ def test_scheduler_deterministic(yi):
     assert s1.health == s2.health
 
 
+def test_final_logits_hold_their_own_row(yi):
+    """A finished request's final logits are its row alone: a view would
+    keep the whole (slots, vocab) host array of its last decode step alive
+    with every result held."""
+    cfg, params = yi
+    _, results = _run_sched(cfg, params)
+    for r in results:
+        assert r.final_logits.shape == (cfg.vocab_size,)
+        assert r.final_logits.base is None
+
+
 def test_scheduler_moe_fp8_smoke():
     cfg = configs.get_reduced("deepseek-moe-16b")
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
