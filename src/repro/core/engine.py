@@ -504,7 +504,10 @@ class BackendSpec:
     storage dtypes named by ``spec.x_dtype``/``spec.w_dtype``) with
     ``x: (..., M, N)`` and ``w: (N, K)`` or broadcast-compatible
     ``(..., N, K)``; it returns ``(..., M, K)`` in any float dtype (the
-    engine downcasts to ``spec.policy.out_dtype``).
+    engine downcasts to ``spec.policy.out_dtype``).  A forward
+    ``w_layer`` dispatch (:meth:`Engine.matmul`) also passes
+    ``w_layer=`` (an int32 scalar) with ``w`` one axis longer, a stack of
+    layers, and ``fn`` contracts against ``w[w_layer]``.
 
     ``capabilities`` is a frozenset of opt-in flags:
 
@@ -801,7 +804,8 @@ def _emit(spec: GemmSpec, backend: str,
 # --------------------------------------------------------------------- #
 # Built-in backends
 # --------------------------------------------------------------------- #
-def _xla_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec) -> jax.Array:
+def _xla_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec,
+            w_layer: Optional[jax.Array] = None) -> jax.Array:
     """``lax.dot_general`` with the engine's accumulation policy.
 
     Honors ``spec.layout`` ("layouts" capability): the contraction axis of
@@ -810,7 +814,11 @@ def _xla_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec) -> jax.Array:
     access into the dot, no materialized transpose.  Honors per-operand
     storage dtypes ("operand_dtypes" capability): narrower (FP8) operands
     are widened right at the dot's input, a cast XLA fuses into the
-    contraction — HBM-side the operand stays at storage width."""
+    contraction — HBM-side the operand stays at storage width.  A
+    ``w_layer`` dispatch slices its layer out of the stacked ``wc`` (XLA
+    fuses the slice into the dot)."""
+    if w_layer is not None:
+        wc = jax.lax.dynamic_index_in_dim(wc, w_layer, 0, False)
     policy = spec.policy
     comp = jnp.dtype(policy.compute_dtype)
     if xc.dtype != comp:
@@ -881,7 +889,8 @@ def _pallas_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec,
                interpret: bool = False, bias: Optional[jax.Array] = None,
                fuse_epilogue: bool = False,
                deriv: Optional[jax.Array] = None,
-               bias_grad: bool = False):
+               bias_grad: bool = False,
+               w_layer: Optional[jax.Array] = None):
     """The Pallas RedMulE kernel (X-stationary, W-streamed, store-once Z).
 
     With ``fuse_epilogue=True`` the bias row and ``spec.epilogue`` are
@@ -893,8 +902,14 @@ def _pallas_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec,
     implement the "fused_bwd_epilogue" contract on the 2D kernel: act' is
     applied to the dZ tiles on load and — for ``bias_grad`` — the bias
     grad accumulates as a second kernel output (see
-    :mod:`repro.kernels.redmule_matmul`)."""
+    :mod:`repro.kernels.redmule_matmul`).  ``w_layer``: the batched
+    kernel reads that layer of the stacked ``wc`` in place (a weight GEMM,
+    or a stack whose lead dims broadcast, takes the slice first)."""
     from repro.kernels import ops  # local import: kernels depend on core
+
+    if w_layer is not None and (wc.ndim == 3 or np.broadcast_shapes(
+            xc.shape[:-2], wc.shape[1:-2]) != wc.shape[1:-2]):
+        wc, w_layer = jax.lax.dynamic_index_in_dim(wc, w_layer, 0, False), None
 
     policy, tile, layout = spec.policy, spec.tile, spec.layout
     if not interpret:
@@ -925,11 +940,16 @@ def _pallas_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec,
         return (z, db) if bias_grad else z
     assert not fused_bwd, \
         "fused backward epilogues are a 2D-weight (w_shared) contract"
-    lead = np.broadcast_shapes(xc.shape[:-2], wc.shape[:-2])
+    if w_layer is not None:   # stacked (L, *lead, N, K): read in place
+        lead = wc.shape[1:-2]
+        wb = wc.reshape((wc.shape[0], -1, *wc.shape[-2:]))
+        kw["w_index"] = w_layer
+    else:
+        lead = np.broadcast_shapes(xc.shape[:-2], wc.shape[:-2])
+        wb = jnp.broadcast_to(wc, (*lead, *wc.shape[-2:])).reshape(
+            (-1, *wc.shape[-2:]))
     xb = jnp.broadcast_to(xc, (*lead, *xc.shape[-2:])).reshape(
         (-1, *xc.shape[-2:]))
-    wb = jnp.broadcast_to(wc, (*lead, *wc.shape[-2:])).reshape(
-        (-1, *wc.shape[-2:]))
     z = ops.redmule_matmul_batched(xb, wb, **kw)
     m = xc.shape[-1] if layout == "tn" else xc.shape[-2]
     k = wc.shape[-2] if layout == "nt" else wc.shape[-1]
@@ -940,10 +960,11 @@ def _interpret_fn(xc: jax.Array, wc: jax.Array, *, spec: GemmSpec,
                   bias: Optional[jax.Array] = None,
                   fuse_epilogue: bool = False,
                   deriv: Optional[jax.Array] = None,
-                  bias_grad: bool = False):
+                  bias_grad: bool = False,
+                  w_layer: Optional[jax.Array] = None):
     return _pallas_fn(xc, wc, spec=spec, interpret=True, bias=bias,
                       fuse_epilogue=fuse_epilogue, deriv=deriv,
-                      bias_grad=bias_grad)
+                      bias_grad=bias_grad, w_layer=w_layer)
 
 
 def _pallas_attention(kind: str, operands, *, interpret: bool = False,
@@ -1076,6 +1097,22 @@ def _dispatch_storage(
             nm(policy.grad_storage_dtype))
 
 
+def _operand_storage(policy: prec.Policy, backend: str, x: jax.Array,
+                     w: jax.Array) -> Tuple[Optional[str], Optional[str]]:
+    """``(x_store, w_store)`` of one forward dispatch: the policy's
+    (:func:`_dispatch_storage`), except that an operand already stored in
+    an FP8 dtype is carried as stored to an ``"operand_dtypes"`` backend
+    (an FP8 KV cache read by decode attention; its scales are the
+    caller's)."""
+    xs, ws, _ = _dispatch_storage(policy, backend)
+    if get_backend(backend).supports("operand_dtypes"):
+        if prec.is_fp8(x.dtype):
+            xs = jnp.dtype(x.dtype).name
+        if prec.is_fp8(w.dtype):
+            ws = jnp.dtype(w.dtype).name
+    return xs, ws
+
+
 def _prep_operand(v: jax.Array, storage_dtype, store_name: Optional[str],
                   policy: prec.Policy,
                   ) -> Tuple[jax.Array, Optional[jax.Array]]:
@@ -1088,7 +1125,11 @@ def _prep_operand(v: jax.Array, storage_dtype, store_name: Optional[str],
     (None -> compute): when the backend can't consume narrow storage the
     quantized values are widened back to the compute dtype — the
     quantization point (and therefore the numerics) is backend-invariant.
+    An operand already stored in the FP8 dtype the dispatch carries
+    passes through as it is: no requantize, no widening cast, no scale.
     """
+    if prec.is_fp8(v.dtype) and store_name == jnp.dtype(v.dtype).name:
+        return v, None
     comp = jnp.dtype(policy.compute_dtype)
     sd = jnp.dtype(storage_dtype)
     if prec.is_fp8(sd):
@@ -1391,6 +1432,24 @@ def _prep_xw(ctx: _GradCtx, x: jax.Array, w: jax.Array):
     xd, sx = _prep_operand(x, pol.x_storage_dtype, ctx.spec.x_dtype, pol)
     wd, sw = _prep_operand(w, pol.w_storage_dtype, ctx.spec.w_dtype, pol)
     return xd, wd, sx, sw
+
+
+def _unstack_scaled(policy: prec.Policy, w: jax.Array, w_layer):
+    """A scaled (per-tensor FP8) policy quantizes the one layer it reads:
+    its ``w_layer`` dispatch takes the slice first."""
+    if w_layer is None or not policy.scaled:
+        return w, w_layer
+    return jax.lax.dynamic_index_in_dim(w, w_layer, 0, False), None
+
+
+def _gemm_call_layer(ctx: _GradCtx, x: jax.Array, w: jax.Array,
+                     w_layer: jax.Array) -> jax.Array:
+    """Forward-only :func:`_gemm_call` against layer ``w_layer`` of a
+    stacked ``w``: the backend reads the layer where it lies."""
+    xd, wd, _, _ = _prep_xw(ctx, x, w)
+    _emit_fwd(ctx)
+    z = get_backend(ctx.backend).fn(xd, wd, spec=ctx.spec, w_layer=w_layer)
+    return z.astype(ctx.spec.policy.out_dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -2018,6 +2077,7 @@ class Engine:
         policy=None,
         tile: Optional[tiling.TileConfig] = None,
         backend: Optional[str] = None,
+        w_layer: Optional[jax.Array] = None,
     ) -> jax.Array:
         """Z = X @ W with the RedMulE dataflow.
 
@@ -2029,30 +2089,41 @@ class Engine:
         Differentiable end to end: ``jax.grad`` dispatches dX = dZ·Wᵀ and
         dW = Xᵀ·dZ through the backend registry as transpose-layout specs
         tagged ``matmul_dx`` / ``matmul_dw`` (see the module docstring's
-        backward contract)."""
+        backward contract).
+
+        ``w_layer`` (an int32 scalar; forward only, not differentiable):
+        ``w`` carries one more leading axis, a stack of layers, and the
+        product reads layer ``w_layer`` of it — the Pallas kernels in
+        place, with no slice copied out (decode reading a layer scan's
+        carried KV cache)."""
         policy = self.resolve_policy(policy)
         b = self.resolve_backend(backend)
-        if x.ndim < 2 or w.ndim < 2:
-            raise ValueError(f"matmul needs >=2D operands, got {x.shape} @ {w.shape}")
-        if x.shape[-1] != w.shape[-2]:
-            raise ValueError(f"contraction mismatch: {x.shape} @ {w.shape}")
-        if w.ndim == 2:
+        w, w_layer = _unstack_scaled(policy, w, w_layer)
+        wsh = w.shape if w_layer is None else w.shape[1:]
+        if x.ndim < 2 or len(wsh) < 2:
+            raise ValueError(f"matmul needs >=2D operands, got {x.shape} @ {wsh}")
+        if x.shape[-1] != wsh[-2]:
+            raise ValueError(f"contraction mismatch: {x.shape} @ {wsh}")
+        if len(wsh) == 2:
             lead = x.shape[:-2]
             tag = "mn,nk->mk"
         else:
-            lead = np.broadcast_shapes(x.shape[:-2], w.shape[:-2])
+            lead = np.broadcast_shapes(x.shape[:-2], wsh[:-2])
             tag = "bmn,bnk->bmk"
-        m, n, k = x.shape[-2], x.shape[-1], w.shape[-1]
-        xs, ws, _ = _dispatch_storage(policy, b)
+        m, n, k = x.shape[-2], x.shape[-1], wsh[-1]
+        xs, ws = _operand_storage(policy, b, x, w)
         tile = self.resolve_tile(tile, m=m, n=n, k=k, policy=policy,
                                  backend=b, x_dtype=xs, w_dtype=ws)
         spec = GemmSpec(
             op="matmul", tag=tag, m=m, n=n, k=k,
             batch=int(np.prod(lead, dtype=np.int64)) if lead else 1,
-            policy=policy, tile=tile, w_shared=(w.ndim == 2),
+            policy=policy, tile=tile, w_shared=(len(wsh) == 2),
             x_dtype=xs, w_dtype=ws, scaled=policy.scaled,
         )
-        return _gemm_call(_make_ctx(spec, b, x, w), x, w)
+        ctx = _make_ctx(spec, b, x, w)
+        if w_layer is not None:
+            return _gemm_call_layer(ctx, x, w, w_layer)
+        return _gemm_call(ctx, x, w)
 
     def linear(
         self,
@@ -2128,7 +2199,7 @@ class Engine:
             lead = np.broadcast_shapes(x.shape[:-2], w.shape[:-2])
             tag = "bmn,bnk->bmk"
         m, n, k = x.shape[-2], x.shape[-1], w.shape[-1]
-        xs, ws, _ = _dispatch_storage(policy, bk)
+        xs, ws = _operand_storage(policy, bk, x, w)
         tile = self.resolve_tile(tile, m=m, n=n, k=k, policy=policy,
                                  backend=bk, epilogue=activation,
                                  x_dtype=xs, w_dtype=ws)
@@ -2168,6 +2239,7 @@ class Engine:
         policy=None,
         tile: Optional[tiling.TileConfig] = None,
         backend: Optional[str] = None,
+        w_layer: Optional[jax.Array] = None,
     ) -> jax.Array:
         """Per-group GEMM: ``Z[g] = X[g] @ W[g]`` for every group at once.
 
@@ -2190,6 +2262,8 @@ class Engine:
         cache).  There ``group_sizes`` count the valid stored rows of
         ``w``, i.e. the valid output *columns*: columns at or beyond a
         group's size are zeroed and billed as ``ragged_dim="k"``.
+        ``w_layer`` reads layer ``w_layer`` of a stacked ``w`` (forward
+        only), as in :meth:`matmul`.
 
         Backward: dX/dW run as batched transpose-layout dispatches per
         group (``matmul_dx`` / ``matmul_dw`` events); the masked rows'
@@ -2202,35 +2276,39 @@ class Engine:
             raise ValueError(
                 f"grouped_matmul layout = {layout!r}; known: ('nn', 'nt')")
         nt = layout == "nt"
-        if x.ndim < 3 or w.ndim != 3:
+        w, w_layer = _unstack_scaled(policy, w, w_layer)
+        wsh = w.shape if w_layer is None else w.shape[1:]
+        if x.ndim < 3 or len(wsh) != 3:
             raise ValueError(
                 f"grouped_matmul needs x (..., G, M, N) and w (G, N, K) "
-                f"(or (G, K, N) under 'nt'); got {x.shape} @ {w.shape}")
-        if x.shape[-3] != w.shape[0]:
+                f"(or (G, K, N) under 'nt'); got {x.shape} @ {wsh}")
+        if x.shape[-3] != wsh[0]:
             raise ValueError(
-                f"group mismatch: x has {x.shape[-3]} groups, w has {w.shape[0]}")
-        if x.shape[-1] != w.shape[-1 if nt else -2]:
-            raise ValueError(f"contraction mismatch: {x.shape} @ {w.shape}"
+                f"group mismatch: x has {x.shape[-3]} groups, w has {wsh[0]}")
+        if x.shape[-1] != wsh[-1 if nt else -2]:
+            raise ValueError(f"contraction mismatch: {x.shape} @ {wsh}"
                              f" ({layout})")
         lead = x.shape[:-3]
         m, n = x.shape[-2], x.shape[-1]
-        k = w.shape[-2] if nt else w.shape[-1]
+        k = wsh[-2] if nt else wsh[-1]
         if nt and not get_backend(b).supports("layouts"):
             w, layout = jnp.swapaxes(w, -1, -2), "nn"
-        xs, ws, _ = _dispatch_storage(policy, b)
+        xs, ws = _operand_storage(policy, b, x, w)
         tile = self.resolve_tile(tile, m=m, n=n, k=k, policy=policy,
                                  backend=b, layout=layout,
                                  x_dtype=xs, w_dtype=ws)
         spec = GemmSpec(
             op="grouped_matmul", tag="gmn,gnk->gmk", m=m, n=n, k=k,
             batch=int(np.prod(lead, dtype=np.int64)) if lead else 1,
-            groups=w.shape[0],
+            groups=wsh[0],
             policy=policy, tile=tile, w_shared=True, layout=layout,
             valid_rows=_static_valid_rows(group_sizes, k if nt else m),
             ragged_dim="k" if nt else "m",
             x_dtype=xs, w_dtype=ws, scaled=policy.scaled,
         )
-        z = _gemm_call(_make_ctx(spec, b, x, w), x, w)
+        ctx = _make_ctx(spec, b, x, w)
+        z = (_gemm_call(ctx, x, w) if w_layer is None
+             else _gemm_call_layer(ctx, x, w, w_layer))
         if group_sizes is not None:
             size = jnp.asarray(group_sizes)[:, None]
             if nt:
@@ -2275,7 +2353,7 @@ class Engine:
         m = int(np.prod([dims[l] for l in m_l], dtype=np.int64)) if m_l else 1
         k = int(np.prod([dims[l] for l in k_l], dtype=np.int64)) if k_l else 1
         c = int(np.prod([dims[l] for l in c_l], dtype=np.int64)) if c_l else 1
-        xs, ws, _ = _dispatch_storage(policy, b)
+        xs, ws = _operand_storage(policy, b, x, w)
         tile = self.resolve_tile(tile, m=m, n=c, k=k, policy=policy,
                                  backend=b, x_dtype=xs, w_dtype=ws)
         spec = GemmSpec(

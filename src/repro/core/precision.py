@@ -65,6 +65,7 @@ __all__ = [
     "is_fp8",
     "fp8_max",
     "quantize_fp8",
+    "quantize_fp8_rows",
     "dequantize_fp8",
 ]
 
@@ -284,6 +285,21 @@ def quantize_fp8(v: jax.Array, dtype,
         scale = jnp.where((amax > 0) & jnp.isfinite(amax), amax, 1.0)
     scale = jnp.asarray(scale, jnp.float32)
     return (vf / scale).astype(dt), scale
+
+
+def quantize_fp8_rows(v: jax.Array, dtype) -> Tuple[jax.Array, jax.Array]:
+    """Per-row amax quantization: :func:`quantize_fp8`'s unit-max rule
+    with one scale per row of the last axis.
+
+    Returns ``(q, s)`` with ``s`` of shape ``v.shape[:-1]`` (f32): each row
+    is ``q = v / amax(|row|)``, an all-zero or non-finite row gets ``s =
+    1``.  A row quantized this way is never requantized: its scale is its
+    own, so writing other rows leaves its bytes as they are."""
+    vf = v.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(vf), axis=-1)
+    s = jnp.where((amax > 0) & jnp.isfinite(amax), amax, 1.0)
+    q, _ = quantize_fp8(vf, dtype, scale=s[..., None])
+    return q, s
 
 
 def dequantize_fp8(q: jax.Array, scale: jax.Array,

@@ -165,6 +165,7 @@ def redmule_matmul_batched(
     bias: Optional[jax.Array] = None,
     epilogue: Optional[str] = None,
     layout: str = "nn",
+    w_index: Optional[jax.Array] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Batched Z[b] = act(X[b] @ W[b] + bias); e.g. x: (B, M, N), w: (B, N, K).
@@ -174,7 +175,9 @@ def redmule_matmul_batched(
     working set by B behind the tile chooser's back.  ``bias`` (optional,
     shape ``(K,)`` or ``(1, K)``, shared across the batch) and ``epilogue``
     are fused into the store-once step like the 2D path; ``layout`` selects
-    the operand storage ("nn" | "nt" | "tn")."""
+    the operand storage ("nn" | "nt" | "tn").  ``w_index``: ``w`` is a
+    stack ``(L, B, ...)`` and the kernel reads layer ``w_index`` in place;
+    a stack whose layer would need padding is sliced (and padded) first."""
     B = x.shape[0]
     M, N, K = _logical_dims(x, w, layout)
     if B == 0 or M == 0 or K == 0 or N == 0:
@@ -192,12 +195,15 @@ def redmule_matmul_batched(
             x_dtype=x.dtype, w_dtype=w.dtype,
         )
     Mp, Np, Kp = _padded_dims(M, N, K, tile)
+    w_padded = (Kp, Np) if layout == "nt" else (Np, Kp)
+    if w_index is not None and w.shape[-2:] != w_padded:
+        w, w_index = jax.lax.dynamic_index_in_dim(w, w_index, 0, False), None
     xp, wp = _pad_operands(x, w, layout, Mp, Np, Kp)
     bp = None
     if bias is not None:
         bp = _pad_to(bias.reshape(1, 1, K).astype(policy.accum_dtype),
                      1, Kp)
-    z = redmule_matmul_batched_pallas(xp, wp, bp, tile=tile, policy=policy,
-                                      epilogue=epilogue, layout=layout,
-                                      interpret=interpret)
+    z = redmule_matmul_batched_pallas(xp, wp, bp, w_index, tile=tile,
+                                      policy=policy, epilogue=epilogue,
+                                      layout=layout, interpret=interpret)
     return z[:, :M, :K]

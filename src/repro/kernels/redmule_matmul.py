@@ -472,6 +472,16 @@ def _operand_specs_batched(tile: tiling.TileConfig, layout: str):
                          lambda b, i, j, k: (b, k, j)))
 
 
+def _layer_spec(bs: pl.BlockSpec, stacked: bool = False) -> pl.BlockSpec:
+    """``bs`` under a one-scalar prefetch: the index map takes the layer
+    ref last; a ``stacked`` operand's block reads that layer in place."""
+    im = bs.index_map
+    if stacked:
+        return pl.BlockSpec((None, *bs.block_shape),
+                            lambda *g: (g[-1][0], *im(*g[:-1])))
+    return pl.BlockSpec(bs.block_shape, lambda *g: im(*g[:-1]))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("tile", "policy", "epilogue", "layout", "interpret"),
@@ -480,6 +490,7 @@ def redmule_matmul_batched_pallas(
     x: jax.Array,
     w: jax.Array,
     bias: Optional[jax.Array] = None,
+    w_index: Optional[jax.Array] = None,
     *,
     tile: tiling.TileConfig,
     policy: prec.Policy,
@@ -497,11 +508,17 @@ def redmule_matmul_batched_pallas(
     ``bias`` (optional) is a ``(1, 1, K)`` row in the accumulation dtype,
     shared across the batch, folded — with ``epilogue`` — into the
     store-once step exactly like the 2D kernel (the PR-2 follow-up gap:
-    the batched grid fuses the full bias+activation epilogue now)."""
+    the batched grid fuses the full bias+activation epilogue now).
+
+    ``w_index`` (an int32 scalar): ``w`` carries one more leading axis, a
+    stack of layers, and the kernel reads layer ``w_index`` of it in place
+    — the index is a scalar-prefetch operand of the BlockSpecs, so no
+    slice of the stack is copied out first."""
     _check_layout(layout)
     B = x.shape[0]
-    assert w.shape[0] == B, (x.shape, w.shape)
-    M, N, K = _logical_dims(x.shape[1:], w.shape[1:], layout)
+    wl = w.shape[1:] if w_index is not None else w.shape
+    assert wl[0] == B, (x.shape, w.shape)
+    M, N, K = _logical_dims(x.shape[1:], wl[1:], layout)
     assert M % tile.bm == 0 and N % tile.bn == 0 and K % tile.bk == 0, (
         f"shapes {(M, N, K)} not padded to tiles {tile}"
     )
@@ -525,18 +542,27 @@ def redmule_matmul_batched_pallas(
                                      lambda b, i, j, k: (0, 0, j)))
         operands.append(bias)
 
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tile.bm, tile.bk),
-                               lambda b, i, j, k: (b, i, j)),
+    out_spec = pl.BlockSpec((1, tile.bm, tile.bk),
+                            lambda b, i, j, k: (b, i, j))
+    scratch = [pltpu.VMEM((tile.bm, tile.bk), policy.accum_dtype)]
+    call = dict(
         out_shape=jax.ShapeDtypeStruct((B, M, K), policy.out_dtype),
-        scratch_shapes=[pltpu.VMEM((tile.bm, tile.bk), policy.accum_dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
         name=f"redmule_matmul_batched_{layout}",
-    )(*operands)
+    )
+    if w_index is None:
+        return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
+                              out_specs=out_spec, scratch_shapes=scratch,
+                              **call)(*operands)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid,
+        in_specs=[_layer_spec(bs, stacked=n == 1)
+                  for n, bs in enumerate(in_specs)],
+        out_specs=_layer_spec(out_spec), scratch_shapes=scratch)
+    return pl.pallas_call(lambda _, *refs: kernel(*refs),
+                          grid_spec=grid_spec, **call)(
+        jnp.reshape(w_index, (1,)).astype(jnp.int32), *operands)

@@ -199,8 +199,9 @@ def _run_sched(cfg, params, args) -> None:
                   f"{h['pending']:4d} {h['active_slots']:6d} "
                   f"{h['batch_fill']:.2f}")
         for leaf, d in kv_lib.scale_health(sched.cache).items():
-            print(f"[kv] {leaf}: max_scale={d['max_scale']:.3g} "
-                  f"overflow={d['overflow_total']}")
+            over = ("" if "overflow_total" not in d
+                    else f" overflow={d['overflow_total']}")
+            print(f"[kv] {leaf}: max_scale={d['max_scale']:.3g}{over}")
         # one exactly-billed ragged decode step at the drained lengths
         lengths = [args.prompt_len + args.gen if i == 0 else 0
                    for i in range(scfg.n_slots)]

@@ -9,9 +9,15 @@ Prefill/train uses a q-chunked online attention (flash-style in pure jnp) so
 query against the KV cache.
 
 Caches:
-  * GQA — k/v tensors (B, Hkv, T, hd), updated in place at ``pos``;
+  * GQA — k/v tensors (B, Hkv, T, hd); each call writes only the rows it
+    adds, in place.  An FP8 cache keeps one f32 scale per (slot, kv-head,
+    row) beside them (``k_scale``/``v_scale``, (B, Hkv, T)): a row is
+    quantized once, under its own amax, when it is written, and decode
+    feeds the stored E4M3 rows straight to the kernels;
   * MLA — the *compressed* (c_kv, k_rope) pair (B, T, r[+dr]): the paper's
     store-small / recompute-fat trade, k_nope/v re-expanded on the fly.
+    Its FP8 form keeps a per-tensor delayed scale (the latent has no head
+    axis) and requantizes the whole cache when the scale moves.
 """
 
 from __future__ import annotations
@@ -87,42 +93,39 @@ def mla_schema(cfg) -> Dict[str, Any]:
 # --------------------------------------------------------------------- #
 # Caches
 # --------------------------------------------------------------------- #
-SCALE_HISTORY = 16  # delayed-scaling amax window per cache scale leaf
+SCALE_HISTORY = 16  # delayed-scaling amax window of an MLA cache scale
 
 
-def _init_scale_leaves(lead_shape: Tuple[int, ...]) -> Dict[str, jax.Array]:
-    """Per-head (or per-tensor, ``lead_shape == ()``) delayed-scaling state,
-    stored as plain cache leaves so it rides the cache pytree through
+def _init_scale_leaves() -> Dict[str, jax.Array]:
+    """An MLA cache tensor's per-tensor delayed-scaling state, stored as
+    plain cache leaves so it rides the cache pytree through
     jit/scan/donation: the three fields of :class:`repro.optim.scale.
-    Fp8ScaleState`, broadcast over the leading head dim."""
+    Fp8ScaleState`."""
     return {
-        "scale": jnp.ones(lead_shape, jnp.float32),
-        "amax_history": jnp.zeros((*lead_shape, SCALE_HISTORY), jnp.float32),
-        "overflow_count": jnp.zeros(lead_shape, jnp.int32),
+        "scale": jnp.ones((), jnp.float32),
+        "amax_history": jnp.zeros((SCALE_HISTORY,), jnp.float32),
+        "overflow_count": jnp.zeros((), jnp.int32),
     }
 
 
-def _scale_leaf_axes(head_axes: Tuple) -> Dict[str, Tuple]:
-    return {
-        "scale": head_axes,
-        "amax_history": (*head_axes, None),
-        "overflow_count": head_axes,
-    }
+# logical axes of :func:`_init_scale_leaves`'s leaves
+MLA_SCALE_AXES = {"scale": (), "amax_history": (None,), "overflow_count": ()}
 
 
 def _refresh_scale(sc: Dict[str, jax.Array], new_rows: jax.Array,
                    reduce_axes: Tuple[int, ...]
                    ) -> Tuple[Dict[str, jax.Array], jax.Array]:
-    """Fold the new rows' amax into the delayed-scaling window
-    (:func:`repro.optim.scale.update_fp8_scale`, vmapped over heads) and
-    return ``(updated leaves, applied scale)``.  The applied scale
-    *ratchets* (``max`` with the stored scale): rows quantized under an
-    older scale can only shrink on requantization, never clip."""
+    """Fold the new rows' amax into an MLA cache's delayed-scaling window
+    (:func:`repro.optim.scale.update_fp8_scale`, vmapped over leading
+    layer dims) and return ``(updated leaves, applied scale)``.  The
+    applied scale *ratchets* (``max`` with the stored scale): rows
+    quantized under an older scale can only shrink on requantization,
+    never clip."""
     st = oscale.Fp8ScaleState(
         sc["scale"], sc["amax_history"], sc["overflow_count"])
     amax = jnp.max(jnp.abs(new_rows.astype(jnp.float32)), axis=reduce_axes)
     upd = oscale.update_fp8_scale
-    for _ in range(amax.ndim):   # nest over (layers, heads) leading dims
+    for _ in range(amax.ndim):   # nest over leading layer dims
         upd = jax.vmap(upd)
     st2 = upd(st, amax)
     applied = jnp.maximum(sc["scale"], st2.scale)
@@ -132,6 +135,10 @@ def _refresh_scale(sc: Dict[str, jax.Array], new_rows: jax.Array,
 
 def init_gqa_cache(cfg, batch: int, max_len: int, dtype,
                    storage_dtype=None) -> Dict[str, jax.Array]:
+    """K and V rows ``(B, Hkv, T, hd)``; with an FP8 ``storage_dtype``, one
+    f32 scale per stored row beside each, ``(B, Hkv, T)`` (1 for rows
+    never written: :func:`repro.core.precision.quantize_fp8_rows`'s
+    all-zero rule)."""
     hkv, hd = cfg.n_kv_heads, cfg.head_dim
     shape = (batch, hkv, max_len, hd)
     if storage_dtype is None:
@@ -143,8 +150,8 @@ def init_gqa_cache(cfg, batch: int, max_len: int, dtype,
             f"got {st.name!r}")
     return {
         "k": jnp.zeros(shape, st), "v": jnp.zeros(shape, st),
-        "k_scale": _init_scale_leaves((hkv,)),
-        "v_scale": _init_scale_leaves((hkv,)),
+        "k_scale": jnp.ones(shape[:-1], jnp.float32),
+        "v_scale": jnp.ones(shape[:-1], jnp.float32),
     }
 
 
@@ -165,8 +172,8 @@ def init_mla_cache(cfg, batch: int, max_len: int, dtype,
         "ckv": jnp.zeros((batch, max_len, m.kv_lora_rank), st),
         "kr": jnp.zeros((batch, max_len, m.qk_rope_dim), st),
         # MLA scales are per-tensor: the compressed latent has no head dim
-        "ckv_scale": _init_scale_leaves(()),
-        "kr_scale": _init_scale_leaves(()),
+        "ckv_scale": _init_scale_leaves(),
+        "kr_scale": _init_scale_leaves(),
     }
 
 
@@ -206,33 +213,15 @@ def chunked_attention(
     window: Optional[jax.Array] = None,
     q_chunk: int = 1024,
     scale: Optional[float] = None,
-    kv_group_sizes: Optional[Any] = None,
     policy: prec.Policy,
 ) -> jax.Array:
     """Returns (B, Hkv, G, S, hdv). Scores fp32, never materialized beyond
     one q-chunk (the RedMulE store-once rule applied to attention).
-
-    ``kv_group_sizes`` (serving decode, S == 1 only): per-batch-slot valid
-    kv lengths.  The score GEMM then dispatches through the Engine's
-    ragged ``grouped_matmul`` path — one group per (slot, kv-head), group
-    size = that slot's kv length — so mixed-length decode batches bill
-    flops/bytes for the *valid* kv rows only.  Concrete sizes (numpy, at
-    an instrumentation trace) pin ``valid_rows`` on the event; traced
-    sizes fall back to dense billing with identical numerics."""
+    Serving's ragged decode takes :func:`_ragged_decode_attention`."""
     B, Hkv, G, S, hd = q.shape
     if scale is None:
         scale = hd**-0.5
-    scores_policy = dataclasses.replace(
-        policy, name=policy.name + "_scores", output_dtype=jnp.float32,
-        faithful_accum=False,
-    )
-    if kv_group_sizes is not None:
-        if S != 1:
-            raise ValueError("kv_group_sizes is a decode-only (S == 1) path")
-        return _ragged_decode_attention(
-            q, k, v, q_offset=q_offset, kv_valid=kv_valid, window=window,
-            kv_group_sizes=kv_group_sizes, scale=scale,
-            scores_policy=scores_policy, policy=policy)
+    scores_policy = _scores_policy(policy)
     # Decode: pin the attention dots to the sequence-sharded KV layout —
     # scores/pv become partial over the seq shards (small softmax
     # all-reduces) instead of GSPMD "involuntarily rematerializing" the
@@ -299,10 +288,17 @@ def chunked_attention(
     return out[:, :, :, :S]
 
 
+def _scores_policy(policy: prec.Policy) -> prec.Policy:
+    """The score contractions' policy: fp32 out, plain accumulation."""
+    return dataclasses.replace(
+        policy, name=policy.name + "_scores", output_dtype=jnp.float32,
+        faithful_accum=False)
+
+
 def _ragged_decode_attention(
     q: jax.Array,  # (B, Hkv, G, 1, hd)
-    k: jax.Array,  # (B, Hkv, T, hd)
-    v: jax.Array,  # (B, Hkv, T, hdv)
+    k: jax.Array,  # ([L,] B, Hkv, T, hd)
+    v: jax.Array,  # ([L,] B, Hkv, T, hdv)
     *,
     q_offset: jax.Array,
     kv_valid: jax.Array,
@@ -311,6 +307,9 @@ def _ragged_decode_attention(
     scale: float,
     scores_policy: prec.Policy,
     policy: prec.Policy,
+    k_scale: Optional[jax.Array] = None,  # (B, Hkv, T)
+    v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Mixed-length decode batch through the ragged grouped-GEMM path.
 
@@ -329,25 +328,89 @@ def _ragged_decode_attention(
     block exactly.  PV ``(B·Hkv, G, T) · (B·Hkv, T, hdv)`` is a dense
     batched dispatch: its ragged dim is the *contraction* (masked
     probabilities are exact zeros), which forward grouped GEMMs cannot
-    bill raggedly."""
+    bill raggedly.
+
+    An FP8 cache reaches the kernels as stored: the E4M3 rows are the
+    contractions' operands, widened on load, and their per-row scales
+    (``k_scale``/``v_scale``) apply outside, exactly — the scores' T
+    columns times the K row scales, the probabilities times the V row
+    scales before the PV product.  ``layer`` (a cache stacked over
+    layers): k and v carry a leading layer axis and both contractions read
+    layer ``layer`` of them where it lies (the scales are that layer's)."""
     B, Hkv, G, S, hd = q.shape
-    T = k.shape[2]
+    T = k.shape[-2]
+    stack = () if layer is None else k.shape[:1]
     sizes = kv_group_sizes
     if isinstance(sizes, (list, tuple)):
         sizes = np.asarray(sizes, np.int32)
     gs = (np.repeat(sizes, Hkv) if isinstance(sizes, np.ndarray)
           else jnp.repeat(jnp.asarray(sizes), Hkv))
     s = engine.grouped_matmul(
-        q.reshape(B * Hkv, G, hd), k.reshape(B * Hkv, T, hd),
-        group_sizes=gs, layout="nt", policy=scores_policy)
-    s = s.reshape(B, Hkv, G, 1, T) * scale
+        q.reshape(B * Hkv, G, hd), k.reshape(*stack, B * Hkv, T, hd),
+        group_sizes=gs, layout="nt", policy=scores_policy, w_layer=layer)
+    s = s.reshape(B, Hkv, G, 1, T)
+    s = s * (scale if k_scale is None
+             else scale * k_scale[:, :, None, None, :])
     off = jnp.asarray(q_offset)
     rows = off[:, None] if off.ndim == 1 else off + jnp.arange(1)
     p = _masked_softmax_block(s, rows, kv_valid, True, window)
+    if v_scale is not None:
+        p = p * v_scale[:, :, None, None, :]
     out = engine.matmul(
         p.reshape(B, Hkv, G, T).astype(policy.compute_dtype), v,
-        policy=policy)
+        policy=policy, w_layer=layer)
     return out[:, :, :, None, :]
+
+
+def _write_rows(c: Dict[str, jax.Array], layer, kk: jax.Array,
+                vv: jax.Array, off: jax.Array) -> Dict[str, jax.Array]:
+    """Write this call's K and V rows ``(B, Hkv, S, hd)`` into layer
+    ``layer`` of the stacked GQA cache ``c`` — those rows alone, in place.
+
+    FP8 rows are quantized here, each under its own amax
+    (:func:`repro.core.precision.quantize_fp8_rows`), and their scales
+    written beside them.  Decode (S == 1) writes one row per slot at the
+    slot's own position (``off`` scalar or ``(B,)``); a position at or past
+    the cache's end writes nothing (a parked slot).  Prefill writes its S
+    rows at ``off``."""
+    B, H, S, _ = kk.shape
+    fp8 = "k_scale" in c
+    zero = jnp.zeros((), jnp.int32)
+    li = jnp.asarray(layer, jnp.int32)
+    out = dict(c)
+    for name, rows in (("k", kk), ("v", vv)):
+        if fp8:
+            q, sc = prec.quantize_fp8_rows(rows, c[name].dtype)
+        else:
+            q, sc = rows.astype(c[name].dtype), None
+        if S == 1:
+            # one (slot, head) row per index: the scatter's window is the
+            # row's hd alone, the cache's minor dim, so the cache keeps
+            # its stored layout
+            b = jnp.arange(B)[:, None]
+            h = jnp.arange(H)[None, :]
+            p = jnp.broadcast_to(off, (B,))[:, None]
+            kw = dict(mode="drop", unique_indices=True)
+            out[name] = c[name].at[li, b, h, p].set(q[:, :, 0], **kw)
+            if fp8:
+                out[name + "_scale"] = c[name + "_scale"].at[li, b, h, p].set(
+                    sc[:, :, 0], **kw)
+        else:
+            out[name] = jax.lax.dynamic_update_slice(
+                c[name], q[None], (li, zero, zero, off, zero))
+            if fp8:
+                out[name + "_scale"] = jax.lax.dynamic_update_slice(
+                    c[name + "_scale"], sc[None], (li, zero, zero, off))
+    return out
+
+
+def _widened(rows: jax.Array, scale: Optional[jax.Array], dtype
+             ) -> jax.Array:
+    """One layer's K or V; FP8 rows widened to ``dtype`` under their own
+    scales."""
+    if scale is None:
+        return rows
+    return prec.dequantize_fp8(rows, scale[..., None], dtype)
 
 
 # --------------------------------------------------------------------- #
@@ -360,17 +423,30 @@ def gqa_attention(
     *,
     pos_offset: jax.Array,
     cache: Optional[Dict[str, jax.Array]] = None,
+    layer: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,
     policy: prec.Policy,
     q_chunk: int = 1024,
     kv_group_sizes: Optional[Any] = None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """GQA over ``x``; with a ``cache``, writes this call's K/V rows into it
+    and returns the updated cache.
+
+    ``layer`` (optional): the cache's leaves carry a leading axis of
+    layers (a scanned stack's cache, in the scan's carry) and this call
+    reads and writes layer ``layer`` of them in place.  Decode (S == 1)
+    attends over the cache as stored, its own new row included; with
+    ``kv_group_sizes`` (the scheduler's per-slot kv lengths) it takes the
+    ragged path, which feeds the stored rows to the kernels.  Prefill
+    attends over its own rows as computed, before quantization."""
     B, S, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     g = hq // hkv
     off = jnp.asarray(pos_offset)
     if off.ndim == 1 and S != 1:
         raise ValueError("per-slot pos_offset is a decode-only (S == 1) path")
+    if kv_group_sizes is not None and (S != 1 or cache is None):
+        raise ValueError("kv_group_sizes is a decode-only (S == 1) path")
 
     qkv = engine.matmul(x, params["wqkv"], policy=policy)
     if "bqkv" in params:
@@ -391,66 +467,61 @@ def gqa_attention(
         q = layers.apply_rope(q, cos, sin)
         kk = layers.apply_rope(kk, cos, sin)
 
-    if cache is not None:
-        fp8 = prec.is_fp8(cache["k"].dtype)
-        if fp8:
-            # upcast on read: E4M3 tensors widen to the compute dtype
-            # against the per-head delayed scales stored alongside them
-            ks = cache["k_scale"]["scale"].reshape(1, -1, 1, 1)
-            vs = cache["v_scale"]["scale"].reshape(1, -1, 1, 1)
-            k_prev = prec.dequantize_fp8(cache["k"], ks, kk.dtype)
-            v_prev = prec.dequantize_fp8(cache["v"], vs, vv.dtype)
-        else:
-            k_prev, v_prev = cache["k"], cache["v"]
-        if S == 1:
-            # decode: masked merge — elementwise over the (possibly
-            # TP-sharded) cache sequence dim, so no gather is forced the way
-            # a dynamic-update-slice at a traced position would; a per-slot
-            # (B,) pos_offset broadcasts each slot's own hit row
-            T = k_prev.shape[2]
-            hit = (jnp.arange(T)[None, :]
-                   == jnp.reshape(off, (-1, 1)))[:, None, :, None]
-            k_all = jnp.where(hit, kk.astype(k_prev.dtype), k_prev)
-            v_all = jnp.where(hit, vv.astype(v_prev.dtype), v_prev)
-        else:
-            zero = jnp.zeros((), jnp.int32)
-            k_all = jax.lax.dynamic_update_slice(
-                k_prev, kk.astype(k_prev.dtype),
-                (zero, zero, pos_offset, zero))
-            v_all = jax.lax.dynamic_update_slice(
-                v_prev, vv.astype(v_prev.dtype),
-                (zero, zero, pos_offset, zero))
-        if fp8:
-            # write-back: refresh the per-head delayed scales with the new
-            # rows' amax, requantize under the (ratcheted) applied scale
-            k_sc, k_as = _refresh_scale(cache["k_scale"], kk, (0, 2, 3))
-            v_sc, v_as = _refresh_scale(cache["v_scale"], vv, (0, 2, 3))
-            k_q, _ = prec.quantize_fp8(
-                k_all, cache["k"].dtype, scale=k_as.reshape(1, -1, 1, 1))
-            v_q, _ = prec.quantize_fp8(
-                v_all, cache["v"].dtype, scale=v_as.reshape(1, -1, 1, 1))
-            new_cache = {"k": k_q, "v": v_q,
-                         "k_scale": k_sc, "v_scale": v_sc}
-        else:
-            new_cache = {"k": k_all, "v": v_all}
-        kv_valid = pos_offset + S
-    else:
+    if cache is None:
         k_all, v_all, new_cache, kv_valid = kk, vv, None, jnp.int32(S)
+    else:
+        c = cache if layer is not None else jax.tree.map(
+            lambda a: a[None], cache)
+        li = 0 if layer is None else layer
+        c = _write_rows(c, li, kk, vv, off)
+        new_cache = c if layer is not None else jax.tree.map(
+            lambda a: a[0], c)
+        kv_valid = pos_offset + S
+
+        def at(name):
+            a = c.get(name)
+            return None if a is None else jax.lax.dynamic_index_in_dim(
+                a, li, 0, False)
+
+        if kv_group_sizes is not None:
+            # the kernels read this layer's K and V where they lie in the
+            # stack; only its row scales are sliced out
+            o = _ragged_decode_attention(
+                q.reshape(B, hkv, g, S, hd), c["k"], c["v"],
+                k_scale=at("k_scale"), v_scale=at("v_scale"),
+                layer=li, q_offset=pos_offset, kv_valid=kv_valid,
+                window=window, kv_group_sizes=kv_group_sizes,
+                scale=(hd**-0.5 if cfg.attention_multiplier is None
+                       else cfg.attention_multiplier),
+                scores_policy=_scores_policy(policy), policy=policy)
+            return _gqa_out(params, o, B, S, hq, hd, policy), new_cache
+        k_all, v_all = (_widened(at(n), at(n + "_scale"), kk.dtype)
+                        for n in ("k", "v"))
+        if S > 1:
+            zero = jnp.zeros((), jnp.int32)
+            start = (zero, zero, pos_offset, zero)
+            k_all = jax.lax.dynamic_update_slice(
+                k_all, kk.astype(k_all.dtype), start)
+            v_all = jax.lax.dynamic_update_slice(
+                v_all, vv.astype(v_all.dtype), start)
 
     k_all = sharding.constrain(k_all, "batch", "kv_heads", "kv_seq", None)
     v_all = sharding.constrain(v_all, "batch", "kv_heads", "kv_seq", None)
-
     qg = q.reshape(B, hkv, g, S, hd)
     o = chunked_attention(
         qg, k_all, v_all,
         q_offset=pos_offset, kv_valid=kv_valid, causal=True,
         window=window, q_chunk=q_chunk, scale=cfg.attention_multiplier,
-        policy=policy, kv_group_sizes=kv_group_sizes,
+        policy=policy,
     )
+    return _gqa_out(params, o, B, S, hq, hd, policy), new_cache
+
+
+def _gqa_out(params, o, B, S, hq, hd, policy):
+    """(B, Hkv, G, S, hd) heads -> the output projection."""
     o = o.reshape(B, hq, S, hd).transpose(0, 2, 1, 3).reshape(B, S, hq * hd)
     o = sharding.constrain(o, "batch", None, "heads")
-    out = engine.matmul(o, params["wo"], policy=policy)
-    return out, new_cache
+    return engine.matmul(o, params["wo"], policy=policy)
 
 
 # --------------------------------------------------------------------- #

@@ -220,21 +220,36 @@ def _norm(cfg, x, scale):
     return layers.rmsnorm(x, scale, cfg.norm_eps)
 
 
-def _run_attn(cfg, p, h, *, pos, cache, window, policy, kv_group_sizes=None):
+def _at_layer(fn, cache, layer):
+    """``fn(layer's cache)`` on layer ``layer`` of a cache stacked over
+    layers, its new cache (the result's second item) written back in
+    place; ``layer`` None: ``cache`` is the layer's own."""
+    if cache is None or layer is None:
+        return fn(cache)
+    out = fn(jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), cache))
+    new = jax.tree.map(
+        lambda a, u: jax.lax.dynamic_update_index_in_dim(
+            a, u.astype(a.dtype), layer, 0), cache, out[1])
+    return (out[0], new, *out[2:])
+
+
+def _run_attn(cfg, p, h, *, pos, cache, window, policy, kv_group_sizes=None,
+              layer=None):
     if cfg.mla:
-        return attention.mla_attention(
-            p, h, cfg, pos_offset=pos, cache=cache, policy=policy,
-            q_chunk=cfg.q_chunk, kv_group_sizes=kv_group_sizes)
+        return _at_layer(lambda c: attention.mla_attention(
+            p, h, cfg, pos_offset=pos, cache=c, policy=policy,
+            q_chunk=cfg.q_chunk, kv_group_sizes=kv_group_sizes), cache, layer)
     return attention.gqa_attention(
-        p, h, cfg, pos_offset=pos, cache=cache, window=window, policy=policy,
-        q_chunk=cfg.q_chunk, kv_group_sizes=kv_group_sizes)
+        p, h, cfg, pos_offset=pos, cache=cache, layer=layer, window=window,
+        policy=policy, q_chunk=cfg.q_chunk, kv_group_sizes=kv_group_sizes)
 
 
 def _attn_block(p, h, cfg, *, pos, cache, window, policy, d_ff=None,
-                kv_group_sizes=None):
+                kv_group_sizes=None, layer=None):
     a, cache = _run_attn(cfg, p["attn"], _norm(cfg, h, p["ln1"]),
                          pos=pos, cache=cache, window=window, policy=policy,
-                         kv_group_sizes=kv_group_sizes)
+                         kv_group_sizes=kv_group_sizes, layer=layer)
     h = h + a
     if cfg.mlp == "glu":
         m = layers.mlp_glu(p["mlp"], _norm(cfg, h, p["ln2"]), act=cfg.act, policy=policy)
@@ -245,10 +260,11 @@ def _attn_block(p, h, cfg, *, pos, cache, window, policy, d_ff=None,
     return h + m, cache, {}
 
 
-def _moe_block(p, h, cfg, *, pos, cache, policy, kv_group_sizes=None):
+def _moe_block(p, h, cfg, *, pos, cache, policy, kv_group_sizes=None,
+               layer=None):
     a, cache = _run_attn(cfg, p["attn"], _norm(cfg, h, p["ln1"]),
                          pos=pos, cache=cache, window=None, policy=policy,
-                         kv_group_sizes=kv_group_sizes)
+                         kv_group_sizes=kv_group_sizes, layer=layer)
     h = h + a
     moe_fn = (moe.moe_forward_shard_map if cfg.moe_impl == "shard_map"
               else moe.moe_forward)
@@ -272,26 +288,27 @@ def _hymba_block(p, h, cfg, *, pos, cache, window, policy):
     return h + mlp_out, new_cache, {}
 
 
-def _pattern_layer(kind, p, h, cfg, *, pos, cache, policy, kv_group_sizes,
-                   fresh):
+def _pattern_layer(kind, p, h, cfg, *, pos, cache, layer, policy,
+                   kv_group_sizes, fresh):
     """One layer of a pattern stack: its mixer (Mamba2 or NoPE GQA) and
-    MLP, each branch scaled by ``residual_multiplier``.  A parked slot's
-    Mamba state runs on unread; admission overwrites it whole
-    (``kv_cache.insert_slot``), so every request starts from its prefill's
-    state."""
+    MLP, each branch scaled by ``residual_multiplier``.  ``cache`` is the
+    kind's cache stacked over its layers, ``layer`` this layer's index in
+    it.  A parked slot's Mamba state runs on unread; admission overwrites
+    it whole (``kv_cache.insert_slot``), so every request starts from its
+    prefill's state."""
     x = _norm(cfg, h, p["ln1"])
     if kind == "mamba":
         with jax.named_scope("mamba_mixer"):
-            m, new_cache = ssm.mamba_mixer(
-                p["mamba"], x, cfg, policy=policy, state=cache,
-                carry=not fresh)
+            m, new_cache = _at_layer(lambda c: ssm.mamba_mixer(
+                p["mamba"], x, cfg, policy=policy, state=c,
+                carry=not fresh), cache, layer)
             if cache is None:
                 new_cache = None
     else:
         with jax.named_scope("attention"):
             m, new_cache = attention.gqa_attention(
-                p["attn"], x, cfg, pos_offset=pos, cache=cache, window=None,
-                policy=policy, q_chunk=cfg.q_chunk,
+                p["attn"], x, cfg, pos_offset=pos, cache=cache, layer=layer,
+                window=None, policy=policy, q_chunk=cfg.q_chunk,
                 kv_group_sizes=kv_group_sizes)
     r = cfg.residual_multiplier
     h = h + r * m
@@ -303,20 +320,20 @@ def _pattern_layer(kind, p, h, cfg, *, pos, cache, policy, kv_group_sizes,
 def _pattern_stack(cfg, params, h, cache, *, pos, policy, kv_group_sizes,
                    fresh):
     """Scan over whole periods of the layer pattern.  The cache rides the
-    scan's carry and each layer's slice of it is updated in place."""
+    scan's carry, each kind's layers on one flat axis, and each layer
+    updates its own slice of it in place."""
     period = layer_period(cfg)
     n = cfg.n_layers // len(period)
     has_cache = cache is not None
+    count = {kind: period.count(kind) for kind in params}
+    if has_cache:   # (periods, layers of the kind) -> one axis of layers
+        cache = {k: jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), c)
+                 for k, c in cache.items()}
 
     def at(a, i, j):
         """Layer (period i, index j) of a stacked leaf."""
         start = (i, j) + (0,) * (a.ndim - 2)
         return jax.lax.dynamic_slice(a, start, (1, 1) + a.shape[2:])[0, 0]
-
-    def put(a, u, i, j):
-        start = (i, j) + (0,) * (a.ndim - 2)
-        return jax.lax.dynamic_update_slice(
-            a, u.astype(a.dtype)[None, None], start)
 
     def body(carry, i):
         h, c = carry
@@ -325,15 +342,13 @@ def _pattern_stack(cfg, params, h, cache, *, pos, policy, kv_group_sizes,
         for kind in period:
             j = seen[kind]
             seen[kind] += 1
-            lc = (jax.tree.map(lambda a: at(a, i, j), c[kind]) if has_cache
-                  else None)
             h, lc = _pattern_layer(
                 kind, jax.tree.map(lambda a: at(a, i, j), params[kind]), h,
-                cfg, pos=pos, cache=lc, policy=policy,
+                cfg, pos=pos, cache=c[kind] if has_cache else None,
+                layer=i * count[kind] + j, policy=policy,
                 kv_group_sizes=kv_group_sizes, fresh=fresh)
             if has_cache:
-                c = dict(c, **{kind: jax.tree.map(
-                    lambda a, u: put(a, u, i, j), c[kind], lc)})
+                c = dict(c, **{kind: lc})
         return (h, c), 0
 
     # the weights are the loop's invariants and each layer slices its own,
@@ -341,7 +356,11 @@ def _pattern_stack(cfg, params, h, cache, *, pos, policy, kv_group_sizes,
     with engine.repeat(n):  # body traced once, runs n periods
         (h, cache), _ = jax.lax.scan(
             _remat(cfg, body), (h, cache if has_cache else 0), jnp.arange(n))
-    return h, (cache if has_cache else None)
+    if not has_cache:
+        return h, None
+    return h, {k: jax.tree.map(
+        lambda a: a.reshape(n, count[k], *a.shape[1:]), c)
+        for k, c in cache.items()}
 
 
 def _xlstm_super_block(p, h, cfg, *, cache, policy):
@@ -391,38 +410,41 @@ def _remat(cfg, fn):
 
 
 def _scan_stack(cfg, block_fn, stack_params, h, cache_stack, windows):
-    """Generic layer scan. cache_stack/windows may be None."""
+    """Generic layer scan. cache_stack/windows may be None.  The cache
+    rides the scan's carry: ``block_fn`` gets the whole stack and the
+    layer's index, and updates that layer's slice in place."""
     has_cache = cache_stack is not None
     has_win = windows is not None
 
     def body(carry, xs):
-        h, aux = carry
+        h, aux = carry[:2]
         lp = xs[0]
-        lc = xs[1] if has_cache else None
-        win = xs[1 + has_cache] if has_win else None
+        win = xs[1] if has_win else None
+        c, li = (carry[2], xs[-1]) if has_cache else (None, None)
         # sequence parallelism: residual stream (and the per-layer saved
         # activations) live sequence-sharded over the TP axis between
         # blocks; no-op unless rules enable seq_sharded
         h = sharding.constrain(h, "batch", "seq_sharded", None)
-        h, lc_new, m = block_fn(lp, h, cache=lc, window=win)
+        h, c, m = block_fn(lp, h, cache=c, layer=li, window=win)
         h = sharding.constrain(h, "batch", "seq_sharded", None)
         aux = {k: aux[k] + m.get(k, 0.0) for k in aux}
-        return (h, aux), (lc_new if has_cache else 0)
+        return ((h, aux, c) if has_cache else (h, aux)), 0
 
     aux0 = (
         {k: jnp.zeros((), jnp.float32)
          for k in ("moe_aux_loss", "moe_z_loss", "moe_drop_frac")}
         if cfg.block_kind == "moe" else {}
     )
+    n_layers = jax.tree_util.tree_leaves(stack_params)[0].shape[0]
     xs: Tuple = (stack_params,)
-    if has_cache:
-        xs = xs + (cache_stack,)
     if has_win:
         xs = xs + (windows,)
-    n_layers = jax.tree_util.tree_leaves(stack_params)[0].shape[0]
+    if has_cache:
+        xs = xs + (jnp.arange(n_layers),)
+    init = (h, aux0, cache_stack) if has_cache else (h, aux0)
     with engine.repeat(n_layers):  # body traced once, runs n_layers times
-        (h, aux), new_cache = jax.lax.scan(_remat(cfg, body), (h, aux0), xs)
-    return h, (new_cache if has_cache else None), aux
+        out, _ = jax.lax.scan(_remat(cfg, body), init, xs)
+    return out[0], (out[2] if has_cache else None), out[1]
 
 
 # --------------------------------------------------------------------- #
@@ -455,9 +477,9 @@ def forward(
     kind = cfg.block_kind
     new_cache: Dict[str, Any] = {}
     if kind == "attn":
-        fn = lambda lp, hh, *, cache, window: _attn_block(
+        fn = lambda lp, hh, *, cache, layer, window: _attn_block(
             lp, hh, cfg, pos=pos, cache=cache, window=window, policy=policy,
-            kv_group_sizes=kv_group_sizes)
+            kv_group_sizes=kv_group_sizes, layer=layer)
         h, nc, aux = _scan_stack(
             cfg, fn, params["layers"], h,
             None if cache is None else cache["layers"], window_array(cfg))
@@ -468,17 +490,19 @@ def forward(
             params["layer0"], h, cfg, pos=pos, cache=c0, window=None,
             policy=policy, d_ff=cfg.moe.dense_ff,
             kv_group_sizes=kv_group_sizes)
-        fn = lambda lp, hh, *, cache, window: _moe_block(
+        fn = lambda lp, hh, *, cache, layer, window: _moe_block(
             lp, hh, cfg, pos=pos, cache=cache, policy=policy,
-            kv_group_sizes=kv_group_sizes)
+            kv_group_sizes=kv_group_sizes, layer=layer)
         h, nc, aux = _scan_stack(
             cfg, fn, params["layers"], h,
             None if cache is None else cache["layers"], None)
         new_cache["layer0"] = nc0
         new_cache["layers"] = nc
     elif kind == "hymba":
-        fn = lambda lp, hh, *, cache, window: _hymba_block(
-            lp, hh, cfg, pos=pos, cache=cache, window=window, policy=policy)
+        fn = lambda lp, hh, *, cache, layer, window: _at_layer(
+            lambda c: _hymba_block(lp, hh, cfg, pos=pos, cache=c,
+                                   window=window, policy=policy),
+            cache, layer)
         h, nc, aux = _scan_stack(
             cfg, fn, params["layers"], h,
             None if cache is None else cache["layers"], window_array(cfg))
@@ -491,8 +515,9 @@ def forward(
         new_cache["layers"] = nc
         aux = {}
     elif kind == "xlstm":
-        fn = lambda lp, hh, *, cache, window: _xlstm_super_block(
-            lp, hh, cfg, cache=cache, policy=policy)
+        fn = lambda lp, hh, *, cache, layer, window: _at_layer(
+            lambda c: _xlstm_super_block(lp, hh, cfg, cache=c, policy=policy),
+            cache, layer)
         h, nc, aux = _scan_stack(
             cfg, fn, params["layers"], h,
             None if cache is None else cache["layers"], None)
@@ -588,8 +613,8 @@ def prefill(params, cfg, batch, max_len: int, storage_dtype=None):
     """Prefill: run the prompt, build the cache, return last-token logits.
 
     ``storage_dtype`` (an FP8 format name) builds the quantized serving
-    cache — the prompt's k/v rows are quantized on write with per-head
-    delayed scales (see :func:`init_cache`)."""
+    cache — the prompt's k/v rows are quantized on write, each GQA row
+    under its own scale (see :func:`init_cache`)."""
     some = batch.get("inputs", batch.get("embeddings"))
     B = some.shape[0]
     cache = init_cache(cfg, B, max_len, dtype=cfg.policy.compute_dtype,
@@ -606,19 +631,18 @@ def cache_axes(cfg, storage_dtype=None):
     """Logical sharding axes for every leaf of ``init_cache``'s output.
 
     With ``storage_dtype`` set (FP8 serving cache) the tree grows the
-    per-head delayed-scaling leaves next to each quantized tensor —
-    mirror of :func:`init_cache`'s structure, leaf for leaf."""
+    scale leaves next to each quantized tensor — GQA's per-row scales,
+    MLA's per-tensor delayed-scaling state — mirror of
+    :func:`init_cache`'s structure, leaf for leaf."""
     kind = cfg.block_kind
     gqa = {"k": ("batch", "kv_heads", "kv_seq", None),
            "v": ("batch", "kv_heads", "kv_seq", None)}
     mla = {"ckv": ("batch", "kv_seq", None), "kr": ("batch", "kv_seq", None)}
     if storage_dtype is not None:
-        gqa = dict(gqa,
-                   k_scale=attention._scale_leaf_axes(("kv_heads",)),
-                   v_scale=attention._scale_leaf_axes(("kv_heads",)))
-        mla = dict(mla,
-                   ckv_scale=attention._scale_leaf_axes(()),
-                   kr_scale=attention._scale_leaf_axes(()))
+        gqa = dict(gqa, k_scale=("batch", "kv_heads", "kv_seq"),
+                   v_scale=("batch", "kv_heads", "kv_seq"))
+        mla = dict(mla, ckv_scale=attention.MLA_SCALE_AXES,
+                   kr_scale=attention.MLA_SCALE_AXES)
     attn = mla if cfg.mla else gqa
     stackax = lambda tree: jax.tree.map(
         lambda ax: ("layers", *ax), tree, is_leaf=lambda x: isinstance(x, tuple))
@@ -651,9 +675,9 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None, storage_dtype=None):
     """Build the decode cache.
 
     ``storage_dtype`` (an FP8 format name, serving) stores the attention
-    k/v tensors narrow with per-head delayed-scaling leaves alongside —
-    the RedMulE mixed-precision trade (narrow storage, wide datapath)
-    applied to the KV cache.  Only attention caches quantize; SSM/xLSTM
+    k/v tensors narrow with their scales alongside (GQA: one per row and
+    head; MLA: a per-tensor delayed scale) — the RedMulE mixed-precision
+    trade (narrow storage, wide datapath) applied to the KV cache.  Only attention caches quantize; SSM/xLSTM
     state stays wide (attn/moe/pattern block kinds only).  A pattern
     stack's cache holds, per layer kind, its layers stacked (periods,
     layers of the kind in a period): KV rows for attention, fp32 SSM

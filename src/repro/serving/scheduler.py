@@ -175,6 +175,8 @@ class Scheduler:
         # recurrent (Mamba2) state beside the KV rows: what the spans
         # report, and how a slot is rebuilt
         self.ssm_layers = sum(1 for t in cfg.layer_types if t == "mamba")
+        # GQA layers: a step writes one row per token in each
+        self.kv_layers = kv_cache.n_gqa_layers(cfg)
         self.slot_state_bytes = kv_cache.state_bytes(cfg)
         self.slots: List[Optional[_Slot]] = [None] * scfg.n_slots
         self.pending: List[Request] = []       # submitted, arrival in future
@@ -378,7 +380,8 @@ class Scheduler:
             prompt_np = np.asarray(r.prompt, np.int32)
             with _span(SPAN_PREFILL, rid=r.rid, plen=len(prompt_np),
                        queued_ms=1e3 * res.queue_s,
-                       ssm_layers=self.ssm_layers):
+                       ssm_layers=self.ssm_layers,
+                       kv_rows_written=len(prompt_np) * self.kv_layers):
                 prompt = jnp.asarray(prompt_np)[None]
                 logits, single = self._guarded_prefill(prompt, r.rid)
                 self.cache = self._insert(self.cache, single, jnp.int32(slot))
@@ -403,9 +406,11 @@ class Scheduler:
     def _arm_guards(self) -> None:
         """(Re)checksum every occupied slot after a cache mutation.
 
-        Re-arming must be global, not per-slot: under FP8 ratcheted
-        delayed scaling any insert may requantize the *whole* pool, so a
-        guard armed before someone else's admission would false-positive.
+        Re-arming is global, not per-slot: in an FP8 MLA pool (ratcheted
+        per-tensor delayed scaling) any insert may requantize the *whole*
+        pool, so a guard armed before someone else's admission would
+        false-positive.  (GQA pools keep each row's own scale: an insert
+        touches its slot alone.)
         """
         if self.scfg.audit_every < 1:
             return
@@ -419,8 +424,9 @@ class Scheduler:
         """Compare every armed guard; quarantine + rebuild mismatches.
 
         All checksums are compared *before* any rebuild: a rebuild's
-        insert may ratchet the FP8 pool scale and requantize co-resident
-        slots, which would trip their still-armed guards spuriously."""
+        insert may ratchet an FP8 MLA pool's scale and requantize
+        co-resident slots, which would trip their still-armed guards
+        spuriously."""
         bad = []
         for i, s in enumerate(self.slots):
             if s is None:
@@ -508,8 +514,8 @@ class Scheduler:
         sizes = np.zeros((n,), np.int32)
         for i, s in enumerate(self.slots):
             if s is None:
-                # parked: rewrites a row its next occupant overwrites anyway
-                pos[i] = self.scfg.max_len - 1
+                # parked: a position past the cache's end writes no row
+                pos[i] = self.scfg.max_len
                 continue
             toks[i, 0] = s.last_token
             pos[i] = s.pos
@@ -602,7 +608,8 @@ class Scheduler:
                 ssm_slots = active if self.ssm_layers else 0
                 with _span(SPAN_DECODE, active=active,
                            slots=self.scfg.n_slots, ssm_slots=ssm_slots,
-                           state_bytes=ssm_slots * self.slot_state_bytes):
+                           state_bytes=ssm_slots * self.slot_state_bytes,
+                           kv_rows_written=active * self.kv_layers):
                     self._decode_once()
                 return True
             if self.pending:  # idle until the next arrival

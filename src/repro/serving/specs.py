@@ -28,7 +28,7 @@ def decode_cache_specs(cfg, rules, mesh, batch: int, max_len: int, *,
                        storage_dtype: Optional[str] = None) -> Tuple:
     """(abstract cache tree, sanitized PartitionSpec tree) for decode.
 
-    ``storage_dtype`` grows the FP8 serving cache's per-head scale leaves
+    ``storage_dtype`` grows the FP8 serving cache's scale leaves
     in both trees (mirror of ``transformer.init_cache``).
     """
     axes = transformer.cache_axes(cfg, storage_dtype)

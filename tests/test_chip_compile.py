@@ -78,23 +78,39 @@ def test_redmule_matmul_decode_shape_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_decode_attention_dispatches_compile(one_chip):
-    # ragged decode attention over 32 slots of 4096: the query group in the
-    # rows, scores on the batched "nt" entry, PV on the batched "nn" one
-    B, T, G = 32, 4096, HQ // HKV
+@pytest.mark.parametrize("slots,hkv,hd,cache,layers", [
+    (32, HKV, HD, jnp.bfloat16, 0),
+    (32, HKV, HD, jnp.float8_e4m3fn, 0),  # yi9b-chat: E4M3 rows as stored
+    (32, HKV, HD, jnp.float8_e4m3fn, 8),  # ... read in place in its stack
+    (48, 8, 64, jnp.float8_e4m3fn, 4),    # granite4h-chat's GQA layers
+], ids=["yi-bf16", "yi-e4m3", "yi-e4m3-stack", "granite-e4m3-stack"])
+def test_decode_attention_dispatches_compile(one_chip, slots, hkv, hd,
+                                             cache, layers):
+    # ragged decode attention over slots of 4096: the query group in the
+    # rows, scores on the batched "nt" entry, PV on the batched "nn" one,
+    # each reading the cache in its stored dtype (E4M3 widened on load);
+    # with ``layers``, one layer of the stacked cache named by a
+    # scalar-prefetch index
+    T, G = 4096, HQ // hkv
+    stack = (layers,) if layers else ()
+    at = dict(w_layer=jnp.int32(layers - 1)) if layers else {}
 
     def attend(q, k, v, sizes):
         s = engine.grouped_matmul(q, k, group_sizes=sizes, layout="nt",
-                                  policy=prec.TPU_BF16, backend="pallas")
+                                  policy=prec.TPU_BF16, backend="pallas",
+                                  **at)
         return engine.matmul(s.astype(jnp.bfloat16), v,
-                             policy=prec.TPU_BF16, backend="pallas")
+                             policy=prec.TPU_BF16, backend="pallas", **at)
 
-    text = _compile(attend, _sds(one_chip, (B * HKV, G, HD)),
-                    _sds(one_chip, (B * HKV, T, HD)),
-                    _sds(one_chip, (B * HKV, T, HD)),
-                    _sds(one_chip, (B * HKV,), jnp.int32))
+    text = _compile(attend, _sds(one_chip, (slots * hkv, G, hd)),
+                    _sds(one_chip, (*stack, slots * hkv, T, hd), cache),
+                    _sds(one_chip, (*stack, slots * hkv, T, hd), cache),
+                    _sds(one_chip, (slots * hkv,), jnp.int32))
     assert text.count("tpu_custom_call") >= 2
     assert "redmule_matmul_batched_nt" in text
+    assert "redmule_matmul_batched_nn" in text
+    if layers and hd % 128 == 0:   # no layer's slice is copied out
+        assert "dynamic-slice" not in text
 
 
 def test_flash_attention_gqa_causal_compiles(one_chip):

@@ -9,7 +9,10 @@
 * the decode step's attention dispatches: one batch entry per (slot, kv
   head) with the G query rows as M, nothing broadcast over G;
 * ``grouped_matmul(layout="nt")``: ragged output columns, their billing,
-  and its gradients.
+  and its gradients;
+* both cache contractions against one layer of a stacked cache
+  (``w_layer``): the kernels read the layer in place and match the same
+  product on the layer's slice.
 """
 
 import dataclasses
@@ -92,15 +95,14 @@ def test_gqa_ragged_decode_matches_dense_fp32(storage):
                                    dtype=cfg.policy.compute_dtype,
                                    storage_dtype=storage)
     layer = jax.tree.map(lambda a: a[0], cache["layers"])
-    # fill the cache with random rows (FP8: under the stored scales)
+    # fill the cache with random rows (FP8: each under its own scale)
     for i, name in enumerate(("k", "v")):
         rows = _rand(3 + i, layer[name].shape, jnp.float32)
         if storage is None:
             layer[name] = rows.astype(layer[name].dtype)
         else:
-            scale = layer[name + "_scale"]["scale"].reshape(1, -1, 1, 1)
-            layer[name] = prec.quantize_fp8(rows, layer[name].dtype,
-                                            scale=scale)[0]
+            layer[name], layer[name + "_scale"] = prec.quantize_fp8_rows(
+                rows, layer[name].dtype)
     x = _rand(5, (B, 1, cfg.d_model), cfg.policy.compute_dtype)
     sizes = np.asarray(SIZES, np.int32)
     pos = jnp.asarray(np.where(sizes == 0, max_len - 1, sizes - 1), jnp.int32)
@@ -197,3 +199,31 @@ def test_grouped_matmul_nt_grads_match_nn(backend, plain_backend):
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+@pytest.mark.parametrize("cache", [jnp.bfloat16, jnp.float8_e4m3fn],
+                         ids=["bf16", "e4m3"])
+def test_cache_contractions_read_one_layer_of_the_stack(backend, cache):
+    """Scores ("nt", ragged) and PV against layer 1 of a stacked cache,
+    at shapes the kernels take without padding, equal the same products
+    on that layer's slice bit for bit."""
+    L, BH, G, T, D = 3, 4, 8, 256, 128
+    q = _rand(10, (BH, G, D))
+    k = _rand(11, (L, BH, T, D)).astype(cache)
+    v = _rand(12, (L, BH, T, D)).astype(cache)
+    p = _rand(13, (BH, G, T))
+    sizes = jnp.asarray([0, 1, 100, T], jnp.int32)
+    pol = prec.TPU_BF16
+    layer = jnp.int32(1)
+    with engine.use_backend(backend):
+        s_in = engine.grouped_matmul(q, k, group_sizes=sizes, layout="nt",
+                                     policy=_scores_policy(pol),
+                                     w_layer=layer)
+        s_at = engine.grouped_matmul(q, k[1], group_sizes=sizes,
+                                     layout="nt", policy=_scores_policy(pol))
+        o_in = engine.matmul(p, v, policy=pol, w_layer=layer)
+        o_at = engine.matmul(p, v[1], policy=pol)
+    np.testing.assert_array_equal(np.asarray(s_in), np.asarray(s_at))
+    np.testing.assert_array_equal(np.asarray(o_in, np.float32),
+                                  np.asarray(o_at, np.float32))

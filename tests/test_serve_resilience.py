@@ -352,8 +352,8 @@ def test_fp8_rebuild_within_e4m3_bound_vs_fp16_oracle(yi):
     sched._audit_slots()
     assert any(e[0] == "kv_quarantine" and e[2] == s0.rid
                for e in sched.trace)
-    # co-resident slot 1: bitwise identical storage (ratchet unmoved —
-    # the rebuilt rows carry the same values, so no pool requantize)
+    # co-resident slot 1: bitwise identical storage (each row keeps its
+    # own scale, so the rebuild's insert touches slot 0 alone)
     for _k, name, leaf, bax in kv_cache.iter_kv_leaves(sched.cache):
         got = np.take(np.asarray(leaf), 1, axis=bax)
         want = np.take(other_before[name], 1, axis=bax)
@@ -368,14 +368,14 @@ def test_fp8_rebuild_within_e4m3_bound_vs_fp16_oracle(yi):
     n = absorbed.shape[0]
     sub = sched.cache["layers"]
     for name in ("k", "v"):
-        sc = np.asarray(sub[f"{name}_scale"]["scale"])
+        sc = np.asarray(sub[f"{name}_scale"])          # (L, B, Hkv, T)
         dq = np.asarray(prec.dequantize_fp8(
-            sub[name], jax.numpy.asarray(sc)[:, None, :, None, None],
+            sub[name], jax.numpy.asarray(sc)[..., None],
             jax.numpy.float32))
         got = dq[:, 0, :, :n]
         want = np.asarray(oracle["layers"][name], np.float32)[:, 0, :, :n]
         bound = (E4M3_EPS * np.abs(want)
-                 + sc[:, None, :, None][..., None] * 2.0 ** -9)
+                 + sc[:, 0, :, :n, None] * 2.0 ** -9)
         assert np.all(np.abs(got - want) <= bound), name
 
 

@@ -98,6 +98,18 @@ def test_decode_spans_hold_one_fetch_then_one_sample(traced):
         assert inner[0][2] <= inner[1][1]
 
 
+def test_spans_count_the_kv_rows_written(yi, traced):
+    """Decode writes one row per active slot and GQA layer, prefill one per
+    prompt token and GQA layer: no span bills a whole-cache pass."""
+    cfg, _ = yi
+    _, _, spans = traced
+    for s in spans:
+        if s[0] == S.SPAN_DECODE:
+            assert s[3]["kv_rows_written"] == s[3]["active"] * cfg.n_layers
+        elif s[0] == S.SPAN_PREFILL:
+            assert s[3]["kv_rows_written"] == s[3]["plen"] * cfg.n_layers
+
+
 def test_every_span_nests_in_one_step(traced):
     _, steps, spans = traced
     step = [s for s in spans if s[0] == S.SPAN_STEP]

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.core import engine
 from repro.core import precision as prec
 from repro.launch import serve
 from repro.models import transformer
@@ -26,27 +27,46 @@ def yi():
 # --------------------------------------------------------------------- #
 # FP8 KV cache numerics
 # --------------------------------------------------------------------- #
-def test_fp8_kv_roundtrip_per_head_bounds():
-    """Per-head quantize -> upcast round-trip stays inside the E4M3
-    relative-precision bound for values within 2^-6 of the head amax."""
+@pytest.mark.parametrize("per", ["head", "row"])
+def test_fp8_kv_roundtrip_per_head_bounds(per):
+    """Quantize -> upcast round-trip stays inside the E4M3
+    relative-precision bound for values within 2^-6 of their scale's amax:
+    one scale per head, or one per row (the GQA cache's own)."""
     rng = np.random.default_rng(0)
     mag = np.array([0.05, 1.0, 30.0, 400.0])  # per-head dynamic ranges
     x = (rng.standard_normal((2, 4, 16, 8)) * mag[None, :, None, None]
          ).astype(np.float32)
-    amax = np.abs(x).max(axis=(0, 2, 3))
-    scale = jnp.asarray(amax)[None, :, None, None]
-    q, _ = prec.quantize_fp8(jnp.asarray(x), FP8, scale=scale)
+    if per == "head":
+        amax = np.abs(x).max(axis=(0, 2, 3), keepdims=True)
+        scale = jnp.asarray(amax)
+        q, _ = prec.quantize_fp8(jnp.asarray(x), FP8, scale=scale)
+    else:
+        amax = np.abs(x).max(axis=-1, keepdims=True)
+        q, scale = prec.quantize_fp8_rows(jnp.asarray(x), FP8)
+        np.testing.assert_array_equal(np.asarray(scale), amax[..., 0])
+        scale = scale[..., None]
     dq = np.asarray(prec.dequantize_fp8(q, scale, jnp.float32))
     err = np.abs(dq - x)
-    for h in range(4):
-        m = np.abs(x[:, h]) >= amax[h] * 2.0 ** -6
-        assert np.all(err[:, h][m] <= E4M3_EPS * np.abs(x[:, h][m]) * 1.001), \
-            f"head {h}: relative error above 2^-3"
+    m = np.abs(x) >= np.broadcast_to(amax, x.shape) * 2.0 ** -6
+    assert np.all(err[m] <= E4M3_EPS * np.abs(x[m]) * 1.001), \
+        "relative error above 2^-3"
+
+
+def _dequant(cache, name):
+    """An FP8 GQA cache's rows under their own per-row scales."""
+    return np.asarray(prec.dequantize_fp8(
+        cache[name], cache[name + "_scale"][..., None], jnp.float32))
+
+
+def _e4m3_bound(want, row_scale):
+    """Relative 2^-3 for normalized values plus the subnormal grid's
+    absolute term (the row's scale * 2^-9) below scale * 2^-6."""
+    return E4M3_EPS * np.abs(want) + np.asarray(row_scale)[..., None] * 2.0 ** -9
 
 
 def test_prefill_fp8_cache_rows_match_fp16_within_bound(yi):
-    """The FP8 prefill cache's dequantized k/v rows match the FP16 cache
-    within the per-head E4M3 bound (upcast-on-read inside attention)."""
+    """The FP8 prefill cache's k/v rows, widened under their per-row
+    scales, match the FP16 cache within the E4M3 bound."""
     cfg, params = yi
     prompts = jax.random.randint(
         jax.random.PRNGKey(1), (2, 6), 0, cfg.vocab_size, jnp.int32)
@@ -55,17 +75,15 @@ def test_prefill_fp8_cache_rows_match_fp16_within_bound(yi):
                                 storage_dtype=FP8)
     for name in ("k", "v"):
         wide = np.asarray(c16["layers"][name], np.float32)
-        scale = np.asarray(c8["layers"][f"{name}_scale"]["scale"])
-        dq = np.asarray(prec.dequantize_fp8(
-            c8["layers"][name], jnp.asarray(scale)[:, None, :, None, None],
-            jnp.float32))
-        # rows past the prompt are zero in both caches; bound on the rest:
-        # relative 2^-3 for normalized values plus the subnormal grid's
-        # absolute term (scale * 2^-9) for values below scale * 2^-6
+        dq = _dequant(c8["layers"], name)
+        sc = np.asarray(c8["layers"][name + "_scale"])
+        # each written row's scale is its own amax; unwritten rows read 0
+        np.testing.assert_allclose(sc[..., :6], np.abs(wide).max(-1)[..., :6],
+                                   rtol=1e-6)
+        assert np.all(sc[..., 6:] == 1) and np.all(dq[..., 6:, :] == 0)
         err = np.abs(dq - wide)[:, :, :, :6]
-        ref = np.abs(wide)[:, :, :, :6]
-        sub = scale[:, None, :, None, None] * 2.0 ** -9
-        assert np.all(err <= E4M3_EPS * ref + sub), name
+        assert np.all(err <= _e4m3_bound(wide[:, :, :, :6], sc[..., :6])), \
+            name
 
 
 def test_fp8_decode_logits_vs_fp16_oracle(yi):
@@ -97,37 +115,124 @@ def test_fp8_decode_logits_vs_fp16_oracle(yi):
 # --------------------------------------------------------------------- #
 # Slot admission
 # --------------------------------------------------------------------- #
+def _filled_pool(cfg, storage, n=3, max_len=8, seed=9):
+    """A pool whose every slot already holds rows (and, FP8, scales)."""
+    pool = transformer.init_cache(cfg, n, max_len,
+                                  dtype=cfg.policy.compute_dtype,
+                                  storage_dtype=storage)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    return jax.tree.map(
+        lambda a: jax.random.uniform(next(keys), a.shape, jnp.float32,
+                                     0.5, 2.0).astype(a.dtype), pool)
+
+
+def _bytes(a):
+    """An array's stored bits, as unsigned ints of its width."""
+    a = np.asarray(a)
+    return a.view(f"u{a.dtype.itemsize}")
+
+
 @pytest.mark.parametrize("storage", [None, FP8])
 def test_insert_slot_preserves_other_slots(yi, storage):
+    """The slot receives the request's rows and scales bit for bit;
+    co-resident slots keep every byte."""
     cfg, params = yi
-    pool = transformer.init_cache(cfg, 3, 8, dtype=cfg.policy.compute_dtype,
-                                  storage_dtype=storage)
+    pool = _filled_pool(cfg, storage)
     prompts = jax.random.randint(
         jax.random.PRNGKey(2), (1, 5), 0, cfg.vocab_size, jnp.int32)
     _, single = transformer.prefill(params, cfg, {"inputs": prompts}, 8,
                                     storage_dtype=storage)
     out = insert_slot(pool, single, jnp.int32(1),
                       dtype=cfg.policy.compute_dtype)
-    for name in ("k", "v"):
-        if storage is None:
-            got = np.asarray(out["layers"][name], np.float32)
-            want = np.asarray(single["layers"][name], np.float32)[:, 0]
-            np.testing.assert_array_equal(got[:, 1], want)
-            assert np.all(got[:, 0] == 0) and np.all(got[:, 2] == 0)
-        else:
-            sc = jnp.asarray(out["layers"][f"{name}_scale"]["scale"])
-            got = np.asarray(prec.dequantize_fp8(
-                out["layers"][name], sc[:, None, :, None, None], jnp.float32))
-            ssc = jnp.asarray(single["layers"][f"{name}_scale"]["scale"])
-            want = np.asarray(prec.dequantize_fp8(
-                single["layers"][name], ssc[:, None, :, None, None],
-                jnp.float32))[:, 0]
-            # inserted slot within quant tolerance (relative + subnormal
-            # grid at the pool's per-head scale); empty slots stay zero
-            sub = np.asarray(sc)[:, None, :, None, None] * 2.0 ** -9
-            assert np.all(np.abs(got[:, 1] - want)
-                          <= E4M3_EPS * np.abs(want) + sub[:, 0])
-            assert np.all(got[:, 0] == 0) and np.all(got[:, 2] == 0)
+    names = ("k", "v") if storage is None else ("k", "v", "k_scale",
+                                                "v_scale")
+    assert set(out["layers"]) == set(names)
+    for name in names:
+        got, was = _bytes(out["layers"][name]), _bytes(pool["layers"][name])
+        want = _bytes(single["layers"][name])[:, 0]
+        np.testing.assert_array_equal(got[:, 1], want, err_msg=name)
+        np.testing.assert_array_equal(got[:, 0], was[:, 0], err_msg=name)
+        np.testing.assert_array_equal(got[:, 2], was[:, 2], err_msg=name)
+
+
+@pytest.mark.parametrize("storage", [None, FP8])
+def test_decode_step_writes_one_row_per_active_slot(yi, storage):
+    """A scheduler decode step writes exactly one row (and its scale) per
+    active slot and GQA layer, at the slot's position; a parked slot and
+    every other row keep every byte."""
+    cfg, params = yi
+    n, max_len = 4, 8
+    pool = _filled_pool(cfg, storage, n=n, max_len=max_len)
+    pos = np.asarray([3, 0, max_len, 7], np.int32)     # slot 2 parked
+    sizes = np.where(pos < max_len, pos + 1, 0).astype(np.int32)
+    toks = jnp.asarray([[5], [6], [0], [7]], jnp.int32)
+    _, out = jax.jit(lambda c: transformer.serve_step(
+        params, cfg, toks, c, jnp.asarray(pos),
+        kv_group_sizes=jnp.asarray(sizes)))(pool)
+    L = cfg.n_layers
+    for name in pool["layers"]:
+        got, was = _bytes(out["layers"][name]), _bytes(pool["layers"][name])
+        changed = got != was
+        if changed.ndim == 5:                       # (L, B, Hkv, T, hd)
+            changed = changed.any(-1)
+        rows = {tuple(r) for r in np.argwhere(changed.any(2))}
+        want = {(l, b, int(pos[b])) for l in range(L) for b in range(n)
+                if sizes[b]}
+        assert rows == want, name                   # (layer, slot, row)
+
+
+def test_decode_kernels_on_e4m3_match_xla(yi):
+    """A decode step over an FP8 cache, its E4M3 rows fed to the Pallas
+    kernels (interpret), matches the xla backend, which widens them at the
+    dot, within the E4M3 bound; the written caches are bitwise equal."""
+    cfg, params = yi
+    pool = _filled_pool(cfg, FP8, n=4, max_len=32)
+    pos = jnp.asarray([3, 17, 32, 30], jnp.int32)
+    sizes = jnp.asarray([4, 18, 0, 31], jnp.int32)
+    toks = jnp.asarray([[5], [6], [0], [7]], jnp.int32)
+    got = {}
+    for backend in ("xla", "interpret"):
+        with engine.use_backend(backend):
+            got[backend] = jax.jit(lambda c: transformer.serve_step(
+                params, cfg, toks, c, pos, kv_group_sizes=sizes))(pool)
+    (lx, cx), (li, ci) = got["xla"], got["interpret"]
+    active = np.asarray(sizes) > 0
+    lx = np.asarray(lx, np.float32)[active]
+    li = np.asarray(li, np.float32)[active]
+    assert np.all(np.abs(li - lx) <= E4M3_EPS * np.abs(lx) + 2e-2)
+    for a, b in zip(jax.tree.leaves(cx), jax.tree.leaves(ci)):
+        np.testing.assert_array_equal(_bytes(a), _bytes(b))
+
+
+def test_lowered_decode_holds_no_widened_cache(yi):
+    """The scheduler's decode step on the kernel path lowers with the FP8
+    cache only at one byte: no bf16 or f32 array of its (B, Hkv, T, hd)
+    shape (or the (B·Hkv, T, hd) the kernels read)."""
+    import re
+
+    cfg, _ = yi
+    n, max_len = 4, 64
+    scfg = SchedulerConfig(n_slots=n, max_len=max_len, storage_dtype=FP8)
+    cabs = jax.eval_shape(lambda: transformer.init_cache(
+        cfg, n, max_len, dtype=cfg.policy.compute_dtype, storage_dtype=FP8))
+    pabs = transformer.abstract_params(cfg)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    with engine.use_backend("interpret"):
+        text = jax.jit(
+            lambda p, c, t, q, g: transformer.serve_step(
+                p, cfg, t, c, q, kv_group_sizes=g),
+            donate_argnums=(1,)).lower(
+                pabs, cabs, i32(n, 1), i32(n), i32(n)).as_text()
+    hkv, hd = cfg.n_kv_heads, cfg.head_dim
+    wide = {(n, hkv, max_len, hd), (n * hkv, max_len, hd),
+            (cfg.n_layers, n, hkv, max_len, hd)}
+    seen = {}
+    for dims, dt in re.findall(r"tensor<((?:\d+x)+)(\w+)>", text):
+        seen.setdefault(tuple(int(d) for d in dims[:-1].split("x")),
+                        set()).add(dt)
+    assert any("f8E4M3FN" in dts for dts in seen.values())
+    for shape in wide:
+        assert not seen.get(shape, set()) & {"bf16", "f32", "f16"}, shape
 
 
 # --------------------------------------------------------------------- #
